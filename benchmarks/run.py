@@ -971,6 +971,9 @@ def main() -> None:
     unknown = set(names) - set(BENCHES)
     if unknown:
         ap.error(f"unknown benches: {sorted(unknown)}")
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     os.makedirs(OUT_DIR, exist_ok=True)
     print("name,us_per_call,derived")

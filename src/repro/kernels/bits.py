@@ -19,10 +19,10 @@ leading-axis slicing / dynamic_update / all_gather of packed buffers stay
 word-aligned for free.
 
 ``n_bits`` is static (a Python int): jit specializes per width, and the
-pure-reshape/shift formulation below contains no gathers — it runs
-unchanged inside Pallas kernel bodies (TPU has no gather unit; DESIGN.md
-§3). Widths that divide 32 (1, 2, 4, 8, 16) take a cheaper
-whole-words fast path; both paths produce identical layouts.
+pure-reshape/shift formulation below contains no gathers (DESIGN.md §3).
+Mosaic cannot lower its lane-splitting reshapes, so Pallas TPU kernel
+bodies use the bit-identical ``pack_bits_mxu`` / ``unpack_bits_mxu``
+twins, which route lanes through the MXU.
 
 ``packed_nbytes`` is the ONE canonical packed-size formula — FL wire
 accounting, ``autotune.policy._leaf_bits`` and the checkpoint shrink check
@@ -40,7 +40,8 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["fmix32", "fmix32_np", "packed_words", "packed_nbytes",
-           "pack_bits", "unpack_bits", "pack_bits_np", "unpack_bits_np"]
+           "pack_bits", "unpack_bits", "pack_bits_np", "unpack_bits_np",
+           "pack_bits_mxu", "unpack_bits_mxu"]
 
 
 def fmix32(x: jnp.ndarray) -> jnp.ndarray:
@@ -103,8 +104,8 @@ def pack_bits(codes: jnp.ndarray, n_bits: int) -> jnp.ndarray:
     Static ``n_bits``: the loop below unrolls over ONE superblock (the
     lcm(32, n_bits)-bit repeat period — at most 32 elements), so the traced
     program is a handful of static-shift/OR lanes per word regardless of
-    ``n``. No gathers, no bit-matrix blowup — it fuses under jit and runs
-    unchanged inside Pallas kernel bodies (TPU has no gather unit)."""
+    ``n``. No gathers, no bit-matrix blowup — it fuses under jit (Pallas
+    kernel bodies use :func:`pack_bits_mxu`)."""
     n_bits = _check_n_bits(n_bits)
     c = codes.astype(jnp.uint32) & jnp.uint32(_mask32(n_bits))
     n = c.shape[-1]
@@ -213,6 +214,119 @@ def unpack_bits_np(words: np.ndarray, n_bits: int, count: int) -> np.ndarray:
     for j in range(n_bits):
         acc |= b[..., j] << np.uint32(j)
     return acc
+
+
+# ---------------------------------------------------------------------------
+# Kernel-body twins. Mosaic (the TPU Pallas compiler) cannot lower the
+# lane-splitting reshapes above for most widths: interleaving fields across
+# lanes is a lane permutation, and the VPU has no lane gather. These twins
+# move lanes through the MXU instead: a word row is split into four byte
+# planes, and each byte a field needs is routed to the field's lane by a
+# 0/1 selection matmul. Bytes are < 256, so bf16 holds them exactly and the
+# f32 accumulator sums one byte plus zeros (or disjoint bit fields) exactly:
+# the results are the same integers as pack_bits/unpack_bits, bit for bit.
+# Inputs are 2D ([rows, lanes]) and n_bits <= 16 (the kernel code widths).
+# ---------------------------------------------------------------------------
+# bf16 x bf16 products of bytes and 0/1 are exact in one MXU pass; pinned so
+# an ambient jax.default_matmul_precision("highest") cannot request an f32
+# contraction of bf16 operands, which Mosaic rejects
+_EXACT = jax.lax.Precision.DEFAULT
+
+
+def _field_bytes(n_bits: int, count: int):
+    """Per field lane i: first stream byte (i*n_bits >> 3), in-byte shift,
+    and the most bytes any field touches (static)."""
+    start = np.arange(count) * n_bits
+    span = int(((start & 7) + n_bits + 7).max() // 8) if count else 1
+    return start >> 3, start & 7, span
+
+
+def _plane_used(byte_minus_plane: np.ndarray, W: int) -> bool:
+    """Static skip: does any field's stream byte land in byte plane j of a
+    word that exists (byte index - j a multiple of 4 within the row)?"""
+    d = byte_minus_plane
+    return bool(np.any((d % 4 == 0) & (d >= 0) & (d // 4 < W)))
+
+
+def _kernel_width(n_bits: int) -> int:
+    n_bits = _check_n_bits(n_bits)
+    if n_bits > 16:
+        raise ValueError(f"kernel pack/unpack supports n_bits <= 16, "
+                         f"got {n_bits}")
+    return n_bits
+
+
+def unpack_bits_mxu(words: jnp.ndarray, n_bits: int, count: int,
+                    offset: int = 0):
+    """:func:`unpack_bits` for Pallas TPU kernel bodies: ``[R, W]`` uint32
+    words -> ``[R, count]`` uint32 codes of the packed row that starts at
+    word ``offset`` (a lane offset the selection matmul absorbs for free),
+    through byte-plane selection matmuls (see the section comment)."""
+    n_bits, count = _kernel_width(n_bits), int(count)
+    w = words.astype(jnp.uint32)
+    W = w.shape[-1]
+    if W - offset < packed_words(count, n_bits):
+        raise ValueError(
+            f"{W - offset} words cannot hold {count} fields of {n_bits} bits")
+    first, _, span = _field_bytes(n_bits, count)
+    first = first + 4 * offset
+    wi = jax.lax.broadcasted_iota(jnp.int32, (W, count), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (W, count), 1)
+    lane_first = ((lane * n_bits) >> 3) + 4 * offset
+    planes = [((w >> jnp.uint32(8 * j)) & jnp.uint32(0xFF)).astype(
+        jnp.int32).astype(jnp.float32).astype(jnp.bfloat16) for j in range(4)]
+    acc = None
+    for m in range(span):
+        g = None
+        for j in range(4):
+            if not _plane_used(first + m - j, W):
+                continue
+            sel = (4 * wi + j == lane_first + m).astype(jnp.bfloat16)
+            d = jnp.dot(planes[j], sel, precision=_EXACT,
+                        preferred_element_type=jnp.float32)
+            g = d if g is None else g + d
+        if g is None:
+            continue
+        b = g.astype(jnp.int32) << (8 * m)
+        acc = b if acc is None else acc | b
+    shift = (jax.lax.broadcasted_iota(jnp.int32, (1, count), 1)
+             * n_bits) & 7
+    return ((acc >> shift) & ((1 << n_bits) - 1)).astype(jnp.uint32)
+
+
+def pack_bits_mxu(codes: jnp.ndarray, n_bits: int) -> jnp.ndarray:
+    """:func:`pack_bits` for Pallas TPU kernel bodies: ``[R, n]`` codes ->
+    ``[R, packed_words(n)]`` uint32 words. Each field, shifted to its
+    in-byte offset, contributes up to three byte pieces; a selection matmul
+    sums the pieces of every stream byte into its byte plane (the pieces
+    are disjoint bit fields, so the sum is their OR)."""
+    n_bits = _kernel_width(n_bits)
+    n = codes.shape[-1]
+    W = packed_words(n, n_bits)
+    first, _, span = _field_bytes(n_bits, n)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
+    c = codes.astype(jnp.int32) & ((1 << n_bits) - 1)
+    v = c << ((lane * n_bits) & 7)
+    fi = (jax.lax.broadcasted_iota(jnp.int32, (n, W), 0) * n_bits) >> 3
+    ww = jax.lax.broadcasted_iota(jnp.int32, (n, W), 1)
+    planes: list = [None] * 4
+    for m in range(span):
+        piece = ((v >> (8 * m)) & 0xFF).astype(jnp.float32).astype(
+            jnp.bfloat16)
+        for j in range(4):
+            if not _plane_used(first + m - j, W):
+                continue
+            sel = (fi + m == 4 * ww + j).astype(jnp.bfloat16)
+            d = jnp.dot(piece, sel, precision=_EXACT,
+                        preferred_element_type=jnp.float32)
+            planes[j] = d if planes[j] is None else planes[j] + d
+    out = None
+    for j, p in enumerate(planes):
+        if p is None:
+            continue
+        b = p.astype(jnp.int32).astype(jnp.uint32) << jnp.uint32(8 * j)
+        out = b if out is None else out | b
+    return out
 
 
 @functools.partial(jax.jit, static_argnames=("n_bits",))

@@ -1,28 +1,28 @@
 """Backend dispatch registry for the F2P kernel ops (DESIGN.md §3.4).
 
-One explicit, trace-safe selection point for every kernel entry in the repo,
-replacing the former scattered ``interpret=not _on_tpu()`` defaults in
-``f2p_quant.py`` / ``f2p_matmul.py`` and the tracer-probe hack
-(``isinstance(jnp.zeros(()), Tracer)``) in ``ops.py``.
+One explicit selection point for every kernel entry in the repo, replacing
+the former scattered ``interpret=not _on_tpu()`` defaults in
+``f2p_quant.py`` / ``f2p_matmul.py``.
 
 Backends:
 
   ``pallas``            compiled Pallas kernels — the TPU hot path
   ``pallas_interpret``  Pallas in interpreter mode — kernel debugging / CI
-                        parity runs on CPU; slow, never a default inside jit
+                        parity runs on CPU; slow, never a default
   ``xla``               the same tile math as plain jnp under jit — fuses into
-                        surrounding HLO; the host/CPU default, and the only
-                        sane choice inside an outer trace
+                        surrounding HLO; the host/CPU default
 
 Resolution order when no backend is requested:
 
   1. ``F2P_BACKEND`` env var (explicit operator override, e.g. CI matrices)
-  2. inside a jit trace -> ``xla`` — an inner ``pallas_call`` defeats XLA
-     fusion, and interpret-mode pallas inside a traced region is pathological
-     (``jax.core.trace_state_clean()`` makes this decision trace-safe: no
-     tracer is materialized to probe)
-  3. TPU available -> ``pallas``
-  4. otherwise -> ``xla``
+  2. TPU available and the op has a Pallas kernel -> ``pallas``, inside a
+     jit trace or not: the jitted serving round must run the kernels, not
+     their XLA twins
+  3. otherwise -> ``xla``
+
+Callers that must not get a kernel pin ``backend="xla"`` themselves (e.g.
+``optim.compress`` inside ``shard_map``, which has no replication rule for
+a ``pallas_call``).
 
 Ops register per-backend implementations with :func:`register`; callers go
 through :func:`lookup`, which resolves the backend *and* validates that the
@@ -109,31 +109,15 @@ def pallas_variant() -> str:
     return PALLAS if jax.default_backend() == "tpu" else PALLAS_INTERPRET
 
 
-def _tracing() -> bool:
-    """True when called under an active jax trace. Prefers the trace-safe
-    ``jax.core.trace_state_clean`` (nothing is traced to find out); newer jax
-    releases that drop it fall back to a one-off tracer probe."""
-    tsc = getattr(jax.core, "trace_state_clean", None)
-    if tsc is not None:
-        return not tsc()
-    import jax.numpy as jnp
-
-    tracer_cls = getattr(jax.core, "Tracer", ())
-    return isinstance(jnp.zeros(()), tracer_cls)
-
-
 def resolve_backend(backend: str | None = None, *, op: str | None = None) -> str:
     """Resolve a backend name. ``None`` applies the policy in the module doc;
     with ``op`` given, also require that the op implements the result."""
     if backend is None:
         backend = os.environ.get("F2P_BACKEND") or None
     if backend is None:
-        if _tracing():
-            backend = XLA
-        elif jax.default_backend() == "tpu":
-            backend = PALLAS
-        else:
-            backend = XLA
+        has_kernel = op is None or PALLAS in _REGISTRY.get(op, {})
+        backend = (PALLAS if jax.default_backend() == "tpu" and has_kernel
+                   else XLA)
     backend = _canonical(backend)
     if op is not None:
         impls = _REGISTRY.get(op, {})

@@ -14,19 +14,19 @@ running (acc, m, l) state. Byte-aligned codes or f32 KV are never
 materialized in HBM.
 
 GQA head folding: q ``[B, Sq, H, hd]`` with H = K*G is reshaped to
-``[B, K, R, hd]`` rows R = G*Sq (row r = g*Sq + s), so one kernel instance
-per (batch, kv-head) feeds all G query heads (and all Sq query positions)
-against a single streamed KV tile. Causal masks recover the query position
-as ``q_offset + r % Sq``.
+``[B, K, R, hd]`` rows R = G*Sq (row r = g*Sq + s), so each kv head's
+decoded tile feeds all G query heads (and all Sq query positions) at once;
+one kernel step streams the tile of every kv head of one batch row. Causal
+masks recover the query position as ``q_offset + r % Sq``.
 
 Backends (dispatch op ``attention_packed``):
 
-  ``pallas`` / ``pallas_interpret``  the Pallas kernel, grid (B, K, S/tile)
+  ``pallas`` / ``pallas_interpret``  the Pallas kernel, grid (B, S/tile)
                                      with the kv-tile axis innermost —
-                                     sequential, so the (acc, m, l) state
-                                     persists in the revisited output blocks
-                                     exactly like the matmul K-axis
-                                     accumulator
+                                     sequential, so every kv head's
+                                     (acc, m, l) state persists in the
+                                     revisited output blocks exactly like
+                                     the matmul K-axis accumulator
   ``xla``                            the SAME per-tile math (shared helpers
                                      below) as a ``lax.scan`` over kv tiles,
                                      with unpack + decode + attention fused
@@ -50,7 +50,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.f2p import F2PFormat
 from repro.core.qtensor import QTensor
 from repro.kernels import dispatch
-from repro.kernels.bits import unpack_bits
+from repro.kernels.bits import unpack_bits, unpack_bits_mxu
 from repro.kernels.f2p_quant import dequantize_tile_math
 
 __all__ = ["attention_packed", "attention_packed_reference",
@@ -79,11 +79,12 @@ def set_attention_tile(backend: str, n_bits: int, tile: int) -> None:
 # Shared per-tile math — ONE implementation used by the Pallas kernel body
 # AND the xla scan, so the backends agree bitwise.
 # ---------------------------------------------------------------------------
-def _decode_rows(words, scales, fmt: F2PFormat, hd: int):
+def _decode_rows(words, scales, fmt: F2PFormat, hd: int,
+                 unpack=unpack_bits):
     """[..., W] uint32 words + [..., 1] f32 scales -> [..., hd] f32 values:
-    superblock unpack, branch-free decode, per-row scale. Pure jnp — runs
-    unchanged inside Pallas kernel bodies."""
-    codes = unpack_bits(words, fmt.n_bits, hd).astype(jnp.int32)
+    unpack, branch-free decode, per-row scale. Pallas bodies pass the
+    Mosaic-lowerable :func:`unpack_bits_mxu` (same integers, bit for bit)."""
+    codes = unpack(words, fmt.n_bits, hd).astype(jnp.int32)
     return dequantize_tile_math(codes, fmt, jnp.float32) * scales
 
 
@@ -176,14 +177,23 @@ def _attention_xla(q3, kw, ks, vw, vs, lens, *, fmt_k, fmt_v, sq, causal,
 
 
 # ---------------------------------------------------------------------------
-# Pallas kernel: grid (B, K, S/tile), kv-tile axis innermost/sequential; the
-# online-softmax state lives in the revisited (b, h) output blocks (same
-# persistence contract the packed matmul uses for its K-axis accumulator).
+# Pallas kernels: grid (B, S/tile), kv-tile axis innermost/sequential; the
+# online-softmax state of every kv head lives in the revisited per-batch
+# output blocks (same persistence contract the packed matmul uses for its
+# K-axis accumulator). One grid step covers all K heads of its kv tile, fed
+# as lane-dense rows — words [tile, K*W] and scales [tile, K], every kv
+# head side by side — because Mosaic wants the last two block dims to be
+# whole array dims or (8, 128) multiples, and [.., K, W] blocks are 1 head
+# tall. Head h's words start at lane h*W, which the MXU unpack absorbs; its
+# scale column is a masked lane sum (one value plus zeros: exact). Lengths
+# ride in SMEM as scalar-prefetch operands.
 # ---------------------------------------------------------------------------
-def _fused_kernel(fmt_k, fmt_v, sq, causal, scale, tile, nt,
-                  q_ref, kw_ref, ks_ref, vw_ref, vs_ref, len_ref,
-                  o_ref, m_ref, l_ref):
-    j = pl.program_id(2)
+def _attend_heads(fmt_k, fmt_v, sq, causal, scale, tile, nt, kw, ks, vw, vs,
+                  q_ref, o_ref, m_ref, l_ref, kvlen, qoff):
+    """Fold kv tile ``program_id(1)`` (rows ``kw``/``vw`` [tile, K*W],
+    ``ks``/``vs`` [tile, K]) into every head's running (acc, m, l). The
+    per-head math is the xla scan's, op for op."""
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -191,25 +201,52 @@ def _fused_kernel(fmt_k, fmt_v, sq, causal, scale, tile, nt,
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    R, hd = q_ref.shape[-2], q_ref.shape[-1]
-    q2 = q_ref[...].reshape(R, hd)
-    k_t = _decode_rows(kw_ref[...].reshape(tile, -1),
-                       ks_ref[...].reshape(tile, 1), fmt_k, hd)
-    v_t = _decode_rows(vw_ref[...].reshape(tile, -1),
-                       vs_ref[...].reshape(tile, 1), fmt_v, hd)
-    valid = _tile_mask(j, tile, R, sq, causal, len_ref[0, 0], len_ref[0, 1])
-    acc, m, l = _online_step(q2, k_t, v_t, valid,
-                             o_ref[...].reshape(R, hd),
-                             m_ref[...].reshape(R, 1),
-                             l_ref[...].reshape(R, 1), scale)
-    o_ref[...] = acc.reshape(o_ref.shape)
-    m_ref[...] = m.reshape(m_ref.shape)
-    l_ref[...] = l.reshape(l_ref.shape)
+    K, R, hd = q_ref.shape
+    Wk, Wv = kw.shape[-1] // K, vw.shape[-1] // K
+    lane = jax.lax.broadcasted_iota(jnp.int32, ks.shape, 1)
+    valid = _tile_mask(j, tile, R, sq, causal, kvlen, qoff)
+
+    def head(words, scales, fmt, W, h):
+        col = jnp.sum(jnp.where(lane == h, scales, 0.0), axis=1,
+                      keepdims=True)
+        return _decode_rows(
+            words, col, fmt, hd,
+            lambda w, n, c: unpack_bits_mxu(w, n, c, offset=h * W))
+
+    for h in range(K):
+        acc, m, l = _online_step(q_ref[h], head(kw, ks, fmt_k, Wk, h),
+                                 head(vw, vs, fmt_v, Wv, h), valid,
+                                 o_ref[h], m_ref[h], l_ref[h], scale)
+        o_ref[h] = acc
+        m_ref[h] = m
+        l_ref[h] = l
 
     @pl.when(j == nt - 1)
     def _fin():
-        o_ref[...] = _finalize(o_ref[...].reshape(R, hd),
-                               l_ref[...].reshape(R, 1)).reshape(o_ref.shape)
+        for h in range(K):
+            o_ref[h] = _finalize(o_ref[h], l_ref[h])
+
+
+def _fused_kernel(fmt_k, fmt_v, sq, causal, scale, tile, nt,
+                  len_ref, q_ref, kw_ref, ks_ref, vw_ref, vs_ref,
+                  o_ref, m_ref, l_ref):
+    b = pl.program_id(0)
+    _attend_heads(fmt_k, fmt_v, sq, causal, scale, tile, nt, kw_ref[...],
+                  ks_ref[...], vw_ref[...], vs_ref[...], q_ref, o_ref,
+                  m_ref, l_ref, len_ref[b, 0], len_ref[b, 1])
+
+
+def _kernel_out(B: int, K: int, R: int, hd: int, index_map):
+    """Out specs + shapes of the per-batch (acc, m, l) state blocks."""
+    specs = [pl.BlockSpec((None, K, R, d), index_map) for d in (hd, 1, 1)]
+    shapes = [jax.ShapeDtypeStruct((B, K, R, d), jnp.float32)
+              for d in (hd, 1, 1)]
+    return specs, shapes
+
+
+def _heads_in_lanes(x):
+    """[..., K, W] -> [..., K*W]: every kv head of a position in one row."""
+    return x.reshape(x.shape[:-2] + (x.shape[-2] * x.shape[-1],))
 
 
 @functools.partial(jax.jit, static_argnames=("fmt_k", "fmt_v", "sq", "causal",
@@ -227,32 +264,24 @@ def _attention_pallas(q3, kw, ks, vw, vs, lens, *, fmt_k, fmt_v, sq, causal,
         ks = jnp.pad(ks, ((0, 0), (0, pad), (0, 0), (0, 0)))
         vw = jnp.pad(vw, ((0, 0), (0, pad), (0, 0), (0, 0)))
         vs = jnp.pad(vs, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    Wk, Wv = kw.shape[-1], vw.shape[-1]
     scale = 1.0 / math.sqrt(hd)   # static: python float, f32 at use sites
+    kv = [_heads_in_lanes(x) for x in (kw, ks, vw, vs)]
+    out_specs, out_shape = _kernel_out(B, K, R, hd,
+                                       lambda b, j, lens: (b, 0, 0, 0))
     out, _, _ = pl.pallas_call(
         functools.partial(_fused_kernel, fmt_k, fmt_v, sq, causal, scale,
                           tile, nt),
-        grid=(B, K, nt),
-        in_specs=[
-            pl.BlockSpec((1, 1, R, hd), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, tile, 1, Wk), lambda b, h, j: (b, j, h, 0)),
-            pl.BlockSpec((1, tile, 1, 1), lambda b, h, j: (b, j, h, 0)),
-            pl.BlockSpec((1, tile, 1, Wv), lambda b, h, j: (b, j, h, 0)),
-            pl.BlockSpec((1, tile, 1, 1), lambda b, h, j: (b, j, h, 0)),
-            pl.BlockSpec((1, 2), lambda b, h, j: (b, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, R, hd), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, R, 1), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, R, 1), lambda b, h, j: (b, h, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, K, R, hd), jnp.float32),
-            jax.ShapeDtypeStruct((B, K, R, 1), jnp.float32),
-            jax.ShapeDtypeStruct((B, K, R, 1), jnp.float32),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, nt),
+            in_specs=[pl.BlockSpec((None, K, R, hd),
+                                   lambda b, j, lens: (b, 0, 0, 0))]
+            + [pl.BlockSpec((None, tile, x.shape[-1]),
+                            lambda b, j, lens: (b, j, 0)) for x in kv],
+            out_specs=out_specs),
+        out_shape=out_shape,
         interpret=interpret,
-    )(q3, kw, ks, vw, vs, lens)
+    )(lens, q3, *kv)
     return out
 
 
@@ -330,7 +359,7 @@ def attention_packed(q, kq: QTensor, vq: QTensor, *, kv_len=None,
 # ---------------------------------------------------------------------------
 # Paged variant: the KV never leaves the pool. Instead of a dense per-request
 # cache row [B, S, K, hd], each batch row carries an ordered page-id list into
-# the pool slabs [P, page_tokens, K, *] (``serve.paging.PagedKVPool``, the
+# the pool slabs [P, page_tokens, K*words] (``serve.paging.PagedKVPool``, the
 # leading layer-group axis stripped by the model's scan). Every kv tile
 # gathers its packed uint32 words and per-row scales THROUGH the page table —
 # word-granular by construction, since §9's block=head_dim packing gives every
@@ -346,85 +375,36 @@ def attention_packed(q, kq: QTensor, vq: QTensor, *, kv_len=None,
                                              "causal", "tile"))
 def _attention_paged_xla(q3, kw, ks, vw, vs, pages, lens, *, fmt_k, fmt_v,
                          sq, causal, tile):
-    B, K, R, hd = q3.shape
-    T = kw.shape[1]
-    ppt = tile // T
-    nt = pages.shape[1] // ppt
-    pgt = pages.reshape(B, nt, ppt).transpose(1, 0, 2)   # [nt, B, ppt]
-    kvlen, qoff = lens[:, 0], lens[:, 1]
-    scale = 1.0 / math.sqrt(hd)
-    step = jax.vmap(jax.vmap(_online_step, in_axes=(0, 0, 0, None, 0, 0, 0,
-                                                    None)),
-                    in_axes=(0, 0, 0, 0, 0, 0, 0, None))
+    """Gather the table's page rows into a dense cache row (a pure word
+    copy), then run the dense xla tile loop: one scan body for both the
+    paged and the dense twin, so they agree bit for bit."""
+    K = q3.shape[1]
 
-    def gather_tile(slab_w, slab_s, pj, fmt):
-        # slab [P, T, K, *], pj [B, ppt] -> [B, K, tile, hd] f32
-        w = jnp.take(slab_w, pj, axis=0)                 # [B, ppt, T, K, W]
-        s = jnp.take(slab_s, pj, axis=0)
-        x = _decode_rows(w, s, fmt, hd)                  # [B, ppt, T, K, hd]
-        return x.reshape(B, tile, K, hd).transpose(0, 2, 1, 3)
+    def dense(slab):
+        x = jnp.take(slab, pages, axis=0)              # [B, n, T, K*W|K]
+        return x.reshape(x.shape[0], x.shape[1] * x.shape[2], K, -1)
 
-    def body(carry, inp):
-        acc, m, l = carry
-        j, pj = inp
-        kb = gather_tile(kw, ks, pj, fmt_k)
-        vb = gather_tile(vw, vs, pj, fmt_v)
-        valid = jax.vmap(
-            lambda kl, qo: _tile_mask(j, tile, R, sq, causal, kl, qo)
-        )(kvlen, qoff)                                   # [B, R, tile]
-        return step(q3, kb, vb, valid, acc, m, l, scale), None
-
-    acc0 = jnp.zeros((B, K, R, hd), jnp.float32)
-    m0 = jnp.full((B, K, R, 1), -jnp.inf, jnp.float32)
-    l0 = jnp.zeros((B, K, R, 1), jnp.float32)
-    (acc, m, l), _ = jax.lax.scan(body, (acc0, m0, l0),
-                                  (jnp.arange(nt), pgt))
-    return _finalize(acc, l)
+    return _attention_xla(q3, dense(kw), dense(ks), dense(vw), dense(vs),
+                          lens, fmt_k=fmt_k, fmt_v=fmt_v, sq=sq,
+                          causal=causal, tile=tile)
 
 
-def _paged_kernel(fmt_k, fmt_v, sq, causal, scale, tile, nt, ppt, T,
-                  ids_ref, *refs):
-    """Pallas body: the grid's kv step j receives its tile as ``ppt``
+def _paged_kernel(fmt_k, fmt_v, sq, causal, scale, tile, nt, ppt,
+                  ids_ref, len_ref, q_ref, *refs):
+    """Pallas body: the grid's kv step j receives its word tile as ``ppt``
     separate page blocks, DMA'd straight from the pool slabs through the
     scalar-prefetched page table (the index_maps below read ``ids_ref``).
     Concatenating the page blocks re-forms the contiguous tile, after which
     the math is byte-for-byte the dense kernel's."""
-    q_ref = refs[0]
-    kw_refs = refs[1:1 + ppt]
-    ks_refs = refs[1 + ppt:1 + 2 * ppt]
-    vw_refs = refs[1 + 2 * ppt:1 + 3 * ppt]
-    vs_refs = refs[1 + 3 * ppt:1 + 4 * ppt]
-    len_ref = refs[1 + 4 * ppt]
-    o_ref, m_ref, l_ref = refs[2 + 4 * ppt:5 + 4 * ppt]
-    j = pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    R, hd = q_ref.shape[-2], q_ref.shape[-1]
-    q2 = q_ref[...].reshape(R, hd)
-    kw_t = jnp.concatenate([r[...].reshape(T, -1) for r in kw_refs], axis=0)
-    ks_t = jnp.concatenate([r[...].reshape(T, 1) for r in ks_refs], axis=0)
-    vw_t = jnp.concatenate([r[...].reshape(T, -1) for r in vw_refs], axis=0)
-    vs_t = jnp.concatenate([r[...].reshape(T, 1) for r in vs_refs], axis=0)
-    k_t = _decode_rows(kw_t, ks_t, fmt_k, hd)
-    v_t = _decode_rows(vw_t, vs_t, fmt_v, hd)
-    valid = _tile_mask(j, tile, R, sq, causal, len_ref[0, 0], len_ref[0, 1])
-    acc, m, l = _online_step(q2, k_t, v_t, valid,
-                             o_ref[...].reshape(R, hd),
-                             m_ref[...].reshape(R, 1),
-                             l_ref[...].reshape(R, 1), scale)
-    o_ref[...] = acc.reshape(o_ref.shape)
-    m_ref[...] = m.reshape(m_ref.shape)
-    l_ref[...] = l.reshape(l_ref.shape)
-
-    @pl.when(j == nt - 1)
-    def _fin():
-        o_ref[...] = _finalize(o_ref[...].reshape(R, hd),
-                               l_ref[...].reshape(R, 1)).reshape(o_ref.shape)
+    kw, vw = (
+        jnp.concatenate([r[...] for r in refs[i * ppt:(i + 1) * ppt]],
+                        axis=0)
+        for i in range(2))
+    ks_ref, vs_ref, o_ref, m_ref, l_ref = refs[2 * ppt:]
+    ks, vs = ks_ref[...], vs_ref[...]
+    b = pl.program_id(0)
+    _attend_heads(fmt_k, fmt_v, sq, causal, scale, tile, nt, kw, ks, vw, vs,
+                  q_ref, o_ref, m_ref, l_ref, len_ref[b, 0], len_ref[b, 1])
 
 
 @functools.partial(jax.jit, static_argnames=("fmt_k", "fmt_v", "sq", "causal",
@@ -435,43 +415,40 @@ def _attention_paged_pallas(q3, kw, ks, vw, vs, pages, lens, *, fmt_k, fmt_v,
     T = kw.shape[1]
     ppt = tile // T
     nt = pages.shape[1] // ppt
-    Wk, Wv = kw.shape[-1], vw.shape[-1]
     scale = 1.0 / math.sqrt(hd)
 
-    def page_spec(W, p):
-        # one page block per spec: row p of kv tile j lives at slab page
+    def page_spec(x, p):
+        # one page block per spec: page p of kv tile j lives at slab page
         # ids[b, j*ppt + p] — the indirection happens in the index_map, so
         # the kernel never sees a dense row and each page is one DMA
         return pl.BlockSpec(
-            (1, T, 1, W),
-            lambda b, h, j, ids, _p=p: (ids[b, j * ppt + _p], 0, h, 0))
+            (None, T, x.shape[-1]),
+            lambda b, j, ids, lens, _p=p: (ids[b, j * ppt + _p], 0, 0))
 
-    in_specs = [pl.BlockSpec((1, 1, R, hd), lambda b, h, j, ids: (b, h, 0, 0))]
-    for W in (Wk, 1, Wv, 1):
-        in_specs.extend(page_spec(W, p) for p in range(ppt))
-    in_specs.append(pl.BlockSpec((1, 2), lambda b, h, j, ids: (b, 0)))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, K, nt),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, R, hd), lambda b, h, j, ids: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, R, 1), lambda b, h, j, ids: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, R, 1), lambda b, h, j, ids: (b, h, 0, 0)),
-        ],
-    )
+    # the per-token scales ([P, T, K] f32, K lanes wide) are gathered by
+    # XLA into a dense [B, S, K] row: a paged [T, K] block would make XLA
+    # relayout the whole scales slab into the kernel's tiled layout (K=8
+    # lanes padded to 128) on every call
+    def dense(slab):
+        return jnp.take(slab, pages, axis=0).reshape(B, nt * tile, K)
+
+    in_specs = [pl.BlockSpec((None, K, R, hd),
+                             lambda b, j, ids, lens: (b, 0, 0, 0))]
+    for x in (kw, vw):
+        in_specs.extend(page_spec(x, p) for p in range(ppt))
+    in_specs += [pl.BlockSpec((None, tile, K),
+                              lambda b, j, ids, lens: (b, j, 0))] * 2
+    out_specs, out_shape = _kernel_out(
+        B, K, R, hd, lambda b, j, ids, lens: (b, 0, 0, 0))
     out, _, _ = pl.pallas_call(
         functools.partial(_paged_kernel, fmt_k, fmt_v, sq, causal, scale,
-                          tile, nt, ppt, T),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B, K, R, hd), jnp.float32),
-            jax.ShapeDtypeStruct((B, K, R, 1), jnp.float32),
-            jax.ShapeDtypeStruct((B, K, R, 1), jnp.float32),
-        ],
+                          tile, nt, ppt),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(B, nt), in_specs=in_specs,
+            out_specs=out_specs),
+        out_shape=out_shape,
         interpret=interpret,
-    )(pages, q3, *([kw] * ppt), *([ks] * ppt), *([vw] * ppt), *([vs] * ppt),
-      lens)
+    )(pages, lens, q3, *([kw] * ppt), *([vw] * ppt), dense(ks), dense(vs))
     return out
 
 
@@ -493,16 +470,18 @@ def _attn_paged_xla(q3, kw, ks, vw, vs, pages, lens, **kw_static):
 
 
 def _check_slab(qt: QTensor, hd: int, what: str) -> None:
+    """A packed pool slab of logical ``[P, T, K*hd]`` blocked over hd (the
+    ``PagedKVPool`` layout: codes ``[P, T, K*W]``, scales ``[P, T, K]``)."""
     if not isinstance(qt, QTensor):
         raise TypeError(f"{what} must be a QTensor, got {type(qt).__name__}")
     if not qt.packed:
         raise ValueError(f"{what} must be bit-packed (QTensor.packed=True)")
-    if qt.codes.ndim != 4:
-        raise ValueError(f"{what} slab codes must be [n_pages, page_tokens, "
-                         f"K, words], got {qt.codes.shape}")
-    if qt.block != hd or qt.shape[-1] != hd:
+    if qt.block != hd:
         raise ValueError(f"{what} must be blocked over head_dim={hd}, got "
                          f"block={qt.block} shape={qt.shape}")
+    if qt.codes.ndim != 3 or qt.shape[-1] % hd:
+        raise ValueError(f"{what} slab codes must be [n_pages, page_tokens, "
+                         f"K*words], got {qt.codes.shape}")
 
 
 def attention_paged(q, kq: QTensor, vq: QTensor, pages, *, kv_len=None,
@@ -510,22 +489,23 @@ def attention_paged(q, kq: QTensor, vq: QTensor, pages, *, kv_len=None,
                     backend: str | None = None, tile: int | None = None):
     """Fused attention THROUGH a page table — no dense KV row exists.
 
-    q ``[B, Sq, H, hd]``; kq/vq are packed pool-slab QTensors whose codes
-    leaves are ``[n_pages, page_tokens, K, words]`` (a
-    ``serve.paging.PagedKVPool`` slab with the layer-group axis stripped by
-    the model scan); ``pages`` ``[B, max_pages]`` int32 orders each batch
-    row's pages. The logical per-row sequence length is
-    ``max_pages * page_tokens``; ``kv_len``/``q_offset`` behave exactly as in
-    :func:`attention_packed` (positions >= kv_len — including every position
-    of unassigned/garbage page ids — contribute exactly 0.0, because the mask
-    sets their scores to -inf before exp). With the same ``tile``, output is
-    bitwise-identical to :func:`attention_packed` over
-    :func:`gather_pages_to_dense` of the same table.
+    q ``[B, Sq, H, hd]``; kq/vq are packed pool-slab QTensors of logical
+    shape ``[n_pages, page_tokens, K*hd]`` (a ``serve.paging.PagedKVPool``
+    slab with the layer-group axis stripped by the model scan); ``pages``
+    ``[B, max_pages]`` int32 orders each batch row's pages. The logical
+    per-row sequence length is ``max_pages * page_tokens``;
+    ``kv_len``/``q_offset`` behave exactly as in :func:`attention_packed`
+    (positions >= kv_len — including every position of unassigned/garbage
+    page ids — contribute exactly 0.0, because the mask sets their scores to
+    -inf before exp). With the same ``tile``, output is bitwise-identical to
+    :func:`attention_packed` over :func:`gather_pages_to_dense` of the same
+    table.
     """
     B, Sq, H, hd = q.shape
     _check_slab(kq, hd, "kq")
     _check_slab(vq, hd, "vq")
-    P, T, K = kq.codes.shape[0], kq.codes.shape[1], kq.codes.shape[2]
+    P, T = kq.codes.shape[0], kq.codes.shape[1]
+    K = kq.shape[-1] // hd
     if H % K:
         raise ValueError(f"n_heads {H} not a multiple of kv heads {K}")
     pages = jnp.asarray(pages, jnp.int32)
@@ -558,20 +538,21 @@ def attention_paged(q, kq: QTensor, vq: QTensor, pages, *, kv_len=None,
 
 
 def gather_pages_to_dense(qt: QTensor, pages) -> QTensor:
-    """Materialize page tables as a dense cache: slab ``[P, T, K, *]`` +
+    """Materialize page tables as a dense cache: slab ``[P, T, K*hd]`` +
     ``pages [B, maxp]`` -> ``[B, maxp*T, K, hd]`` QTensor. A pure uint32
-    word/scale gather — zero repack, bit-exact by construction. The
-    copy-in comparator for :func:`attention_paged` (and what
+    word/scale gather — zero repack, bit-exact by construction. The copy-in
+    comparator for :func:`attention_paged` (and what
     ``PagedKVPool.load_into_slot`` does for the copy-in engine)."""
+    hd = qt.block
+    _check_slab(qt, hd, "slab")
+    K = qt.shape[-1] // hd
     pages = jnp.asarray(pages, jnp.int32)
-    codes = jnp.take(qt.codes, pages, axis=0)     # [B, maxp, T, K, W]
+    codes = jnp.take(qt.codes, pages, axis=0)     # [B, maxp, T, K*W]
     scales = jnp.take(qt.scales, pages, axis=0)
     B, mp, T = codes.shape[:3]
-    codes = codes.reshape((B, mp * T) + codes.shape[3:])
-    scales = scales.reshape((B, mp * T) + scales.shape[3:])
-    return QTensor.from_parts(codes, scales, qt.fmt, qt.block,
-                              (B, mp * T) + tuple(qt.shape[-2:]),
-                              packed=qt.packed)
+    return QTensor.from_parts(codes.reshape(B, mp * T, K, -1),
+                              scales.reshape(B, mp * T, K, 1), qt.fmt, hd,
+                              (B, mp * T, K, hd), packed=qt.packed)
 
 
 def attention_paged_reference(q, kq: QTensor, vq: QTensor, pages, *,

@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import dispatch
 from repro.kernels.bits import fmix32
@@ -107,12 +108,14 @@ def _sojourn(u: jnp.ndarray, p: jnp.ndarray, log_q: jnp.ndarray) -> jnp.ndarray:
     return jnp.maximum(t, 1.0)
 
 
-def _sweep(state, rem, u, p_lut, run_lut, logq_lut, kmax):
-    """One vector step: cross the unit run, then one geometric sojourn."""
-    run = jnp.minimum(rem, jnp.take(run_lut, state))
+def _sweep(state, rem, u, p_lut, run_lut, logq_lut, kmax, take=jnp.take):
+    """One vector step: cross the unit run, then one geometric sojourn.
+    ``take(lut, state)`` reads a table (a gather; the Pallas body passes a
+    Mosaic-lowerable select loop that returns the same values)."""
+    run = jnp.minimum(rem, take(run_lut, state))
     state = state + run.astype(jnp.int32)
     rem = rem - run
-    need = _sojourn(u, jnp.take(p_lut, state), jnp.take(logq_lut, state))
+    need = _sojourn(u, take(p_lut, state), take(logq_lut, state))
     adv = need <= rem
     state = jnp.where(adv, jnp.minimum(state + 1, kmax), state)
     # a sojourn exceeding the budget means no advance happens within this
@@ -172,22 +175,29 @@ def counter_advance_xla(state, budget, p_lut, run_lut, logq_lut, key):
 
 
 # ---------------------------------------------------------------------------
-# Pallas backend: fixed-sweep kernel over rows, pre-drawn uniforms
+# Pallas backend: fixed-sweep kernel over the whole (rows, width) register
+# array (sketch depths are below 8 rows, so Mosaic needs whole-array blocks),
+# pre-drawn uniforms, tables in SMEM. The TPU has no vector gather: a table
+# read is a loop of selects over the K table entries.
 # ---------------------------------------------------------------------------
+def _smem_take(lut_ref, idx):
+    """``lut[idx]`` for an SMEM table ref: one select per table entry."""
+    def body(k, acc):
+        return jnp.where(idx == k, lut_ref[k], acc)
+
+    return jax.lax.fori_loop(0, lut_ref.shape[0], body,
+                             jnp.zeros(idx.shape, jnp.float32))
+
+
 def _advance_kernel(sweeps, kmax, state_ref, budget_ref, u_ref, p_ref,
                     run_ref, logq_ref, out_state_ref, out_left_ref):
-    state = state_ref[...].astype(jnp.int32)    # (1, width)
-    rem = budget_ref[...]                       # (1, width) f32
-    u_all = u_ref[...]                          # (1, sweeps, width) f32
-    p_lut = p_ref[...]                          # (K,)
-    run_lut = run_ref[...]                      # (K,)
-    logq_lut = logq_ref[...]                    # (K,)
+    state = state_ref[...].astype(jnp.int32)    # (rows, width)
+    rem = budget_ref[...]                       # (rows, width) f32
 
     def step(t, carry):
         state, rem = carry
-        u = jax.lax.dynamic_index_in_dim(u_all, t, axis=1,
-                                         keepdims=False)  # (1, width)
-        return _sweep(state, rem, u, p_lut, run_lut, logq_lut, kmax)
+        return _sweep(state, rem, u_ref[t], p_ref, run_ref, logq_ref, kmax,
+                      take=_smem_take)
 
     state, rem = jax.lax.fori_loop(0, sweeps, step, (state, rem))
     out_state_ref[...] = state
@@ -199,22 +209,11 @@ def _advance_kernel(sweeps, kmax, state_ref, budget_ref, u_ref, p_ref,
 def _advance_pallas_jit(state, budget, u, p_lut, run_lut, logq_lut, *,
                         sweeps: int, kmax: int, interpret: bool):
     rows, width = state.shape
-    K = p_lut.shape[0]
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     return pl.pallas_call(
         functools.partial(_advance_kernel, sweeps, kmax),
-        grid=(rows,),
-        in_specs=[
-            pl.BlockSpec((1, width), lambda i: (i, 0)),
-            pl.BlockSpec((1, width), lambda i: (i, 0)),
-            pl.BlockSpec((1, sweeps, width), lambda i: (i, 0, 0)),
-            pl.BlockSpec((K,), lambda i: (0,)),
-            pl.BlockSpec((K,), lambda i: (0,)),
-            pl.BlockSpec((K,), lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, width), lambda i: (i, 0)),
-            pl.BlockSpec((1, width), lambda i: (i, 0)),
-        ],
+        in_specs=[pl.BlockSpec(), pl.BlockSpec(), pl.BlockSpec(),
+                  smem, smem, smem],
         out_shape=[
             jax.ShapeDtypeStruct((rows, width), jnp.int32),
             jax.ShapeDtypeStruct((rows, width), jnp.float32),
@@ -229,7 +228,7 @@ def counter_advance_pallas(state, budget, p_lut, run_lut, logq_lut, key, *,
     """Fixed-sweep Pallas advance over a (rows, width) register array.
 
     Uniforms are drawn up front with ``jax.random`` (shape
-    ``(rows, sweeps, width)``) and streamed through the kernel, one slice per
+    ``(sweeps, rows, width)``) and streamed through the kernel, one slice per
     sweep — on a real TPU deployment this slot is where
     ``pltpu.prng_random_bits`` takes over. Budget a cell cannot spend within
     ``sweeps`` sweeps comes back in ``leftover`` — callers either re-issue it
@@ -244,7 +243,7 @@ def counter_advance_pallas(state, budget, p_lut, run_lut, logq_lut, key, *,
                                         sweeps=sweeps, interpret=interpret)
         return st[0], lf[0]
     rows, width = state.shape
-    u = jax.random.uniform(key, (rows, sweeps, width), dtype=jnp.float32,
+    u = jax.random.uniform(key, (sweeps, rows, width), dtype=jnp.float32,
                            minval=jnp.float32(np.finfo(np.float32).tiny))
     kmax = int(p_lut.shape[0]) - 1
     return _advance_pallas_jit(state, jnp.asarray(budget, jnp.float32), u,
@@ -265,22 +264,15 @@ def counter_estimate_xla(state, grid_lut):
 
 
 def _estimate_kernel(state_ref, grid_ref, out_ref):
-    out_ref[...] = jnp.take(grid_ref[...],
-                            state_ref[...].astype(jnp.int32))
+    out_ref[...] = _smem_take(grid_ref, state_ref[...].astype(jnp.int32))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _estimate_pallas_jit(state, grid_lut, *, interpret: bool):
     rows, width = state.shape
-    K = grid_lut.shape[0]
     return pl.pallas_call(
         _estimate_kernel,
-        grid=(rows,),
-        in_specs=[
-            pl.BlockSpec((1, width), lambda i: (i, 0)),
-            pl.BlockSpec((K,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((1, width), lambda i: (i, 0)),
+        in_specs=[pl.BlockSpec(), pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_shape=jax.ShapeDtypeStruct((rows, width), jnp.float32),
         interpret=interpret,
     )(state, grid_lut)

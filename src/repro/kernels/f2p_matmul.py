@@ -8,8 +8,10 @@ the branch-free decode (no LUT/gather — DESIGN.md §3), and feeds the MXU
 tile. Accumulation in f32 across the K grid axis.
 
 Tiling: grid (M/M_T, N/N_T, K/K_T); x tile (M_T,K_T) bf16/f32, codes tile
-(K_T,N_T) uint8, scales tile (K_T/block, N_T) f32, out (M_T,N_T) f32 —
-MXU-aligned multiples of 128 on every matmul dim.
+(K_T,N_T) uint8, out (M_T,N_T) f32 — MXU-aligned multiples of 128 on every
+matmul dim. The scales block is the whole (K/block, N_T) column strip (a
+(K_T/block, N_T) block is too short for Mosaic's 8-sublane rule); each K
+step slices its K_T/block rows out of it.
 
 Oracle: ref_dequant_matmul (pure jnp) — tests sweep shapes/dtypes/formats
 and assert allclose within f32 matmul tolerance.
@@ -17,6 +19,7 @@ and assert allclose within f32 matmul tolerance.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +28,8 @@ from jax.experimental import pallas as pl
 from repro.core.f2p import F2PFormat, Flavor
 from repro.core.qtensor import block_scales
 from repro.kernels import dispatch
-from repro.kernels.bits import pack_bits, packed_words, unpack_bits
+from repro.kernels.bits import (pack_bits, packed_words, unpack_bits,
+                                unpack_bits_mxu)
 from repro.kernels.f2p_quant import dequantize_tile_math, quantize_tile_math
 
 WEIGHT_FMT = F2PFormat(n_bits=8, h_bits=2, flavor=Flavor.SR, signed=True)
@@ -87,6 +91,23 @@ def ref_dequant_matmul(x, codes, scales, fmt: F2PFormat = WEIGHT_FMT,
     return jnp.dot(x.astype(jnp.float32), w)
 
 
+def _scale_tile(w, s_ref, block: int):
+    """w [K_T, N_T] times this K step's scales, one block of rows at a time.
+    Each scale row is picked out of the whole-strip block by a masked sum
+    (one value plus zeros: exact) — Mosaic cannot slice a dynamic row
+    offset that is not a multiple of 8."""
+    kt = w.shape[0]
+    nkb = kt // block
+    strip = s_ref[...]                              # [K/block, N_T]
+    rows = jax.lax.broadcasted_iota(jnp.int32, strip.shape, 0)
+    k0 = pl.program_id(2) * nkb
+    parts = [w[r * block:(r + 1) * block]
+             * jnp.sum(jnp.where(rows == k0 + r, strip, 0.0), axis=0,
+                       keepdims=True)
+             for r in range(nkb)]
+    return parts[0] if nkb == 1 else jnp.concatenate(parts, axis=0)
+
+
 def _kernel(fmt, block, nk, x_ref, c_ref, s_ref, o_ref):
     @pl.when(pl.program_id(2) == 0)
     def _init():
@@ -94,9 +115,7 @@ def _kernel(fmt, block, nk, x_ref, c_ref, s_ref, o_ref):
 
     x = x_ref[...].astype(jnp.float32)              # [M_T, K_T]
     w = dequantize_tile_math(c_ref[...], fmt, jnp.float32)  # [K_T, N_T]
-    kt, nt = w.shape
-    w = (w.reshape(kt // block, block, nt) * s_ref[...][:, None, :])
-    w = w.reshape(kt, nt)
+    w = _scale_tile(w, s_ref, block)
     o_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
 
 
@@ -127,7 +146,7 @@ def _dequant_matmul_jit(x, codes, scales, *, fmt: F2PFormat,
         in_specs=[
             pl.BlockSpec((mt, K_T), lambda i, j, k: (i, k)),
             pl.BlockSpec((K_T, nt), lambda i, j, k: (k, j)),
-            pl.BlockSpec((K_T // block, nt), lambda i, j, k: (k, j)),
+            pl.BlockSpec((K // block, nt), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((mt, nt), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
@@ -140,10 +159,19 @@ def _dequant_matmul_jit(x, codes, scales, *, fmt: F2PFormat,
 # in uint32 words — each grid step streams an (K_T, words(N_T)) WORD tile
 # into VMEM (n_bits/8 bytes per weight: 0.75 B at 6-bit vs the 1 B uint8
 # stream, 2.7x less than bf16) and unpacks in-register immediately before
-# the branch-free decode. Word alignment: N_T = 256 is a multiple of 32, so
-# every column tile covers an integral number of words for any n_bits; rows
-# (the K axis) never share words, so K tiling is unaffected.
+# the branch-free decode. Word alignment: the column tile is widened until
+# its word tile is a multiple of 128 lanes (_packed_col_tile), which also
+# makes it a multiple of 32 codes, so every column tile covers an integral
+# number of words for any n_bits; rows (the K axis) never share words, so K
+# tiling is unaffected.
 # ---------------------------------------------------------------------------
+def _packed_col_tile(N: int, nt0: int, n_bits: int) -> int:
+    """Column tile >= ``nt0`` whose codes AND packed words are multiples of
+    128 lanes (nt * n_bits % 4096 == 0), or all N columns when no such tile
+    divides N."""
+    q = math.lcm(128, 4096 // math.gcd(4096, n_bits))
+    nt = -(-nt0 // q) * q
+    return nt if nt < N and N % nt == 0 else N
 def _packed_kernel(fmt, block, nk, x_ref, w_ref, s_ref, o_ref):
     @pl.when(pl.program_id(2) == 0)
     def _init():
@@ -151,11 +179,9 @@ def _packed_kernel(fmt, block, nk, x_ref, w_ref, s_ref, o_ref):
 
     x = x_ref[...].astype(jnp.float32)              # [M_T, K_T]
     nt = s_ref.shape[-1]
-    codes = unpack_bits(w_ref[...], fmt.n_bits, nt).astype(jnp.int32)
+    codes = unpack_bits_mxu(w_ref[...], fmt.n_bits, nt).astype(jnp.int32)
     w = dequantize_tile_math(codes, fmt, jnp.float32)       # [K_T, N_T]
-    kt, _ = w.shape
-    w = (w.reshape(kt // block, block, nt) * s_ref[...][:, None, :])
-    w = w.reshape(kt, nt)
+    w = _scale_tile(w, s_ref, block)
     o_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
 
 
@@ -187,11 +213,8 @@ def _dequant_matmul_packed_jit(x, words, scales, *, fmt: F2PFormat,
     K2, W = words.shape
     assert K == K2 and K % kt0 == 0 and kt0 % block == 0
     assert W == packed_words(N, fmt.n_bits), (W, N, fmt.n_bits)
-    mt, nt = min(mt0, M), min(nt0, N)
-    assert M % mt == 0 and N % nt == 0
-    if nt != N:
-        # multi-tile columns: tiles must land on word boundaries
-        assert nt % 32 == 0, f"column tile {nt} not word-aligned"
+    mt, nt = min(mt0, M), _packed_col_tile(N, nt0, fmt.n_bits)
+    assert M % mt == 0
     wt = packed_words(nt, fmt.n_bits)
     grid = (M // mt, N // nt, K // kt0)
     return pl.pallas_call(
@@ -200,7 +223,7 @@ def _dequant_matmul_packed_jit(x, words, scales, *, fmt: F2PFormat,
         in_specs=[
             pl.BlockSpec((mt, kt0), lambda i, j, k: (i, k)),
             pl.BlockSpec((kt0, wt), lambda i, j, k: (k, j)),
-            pl.BlockSpec((kt0 // block, nt), lambda i, j, k: (k, j)),
+            pl.BlockSpec((K // block, nt), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((mt, nt), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),

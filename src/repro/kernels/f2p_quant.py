@@ -9,10 +9,11 @@ branch-free VPU lane arithmetic:
            field assembly with variable shifts.
   decode:  field split with variable shifts -> ldexp (exact).
 
-Tiling: elementwise over (rows, cols); BlockSpec tiles of (TILE_R, TILE_C)
-float32 in VMEM, TILE_C a multiple of 128 lanes (the per-block scale axis),
-TILE_R a multiple of 8 sublanes. One grid step touches
-TILE_R*TILE_C*(4+1)+TILE_R*(TILE_C/block)*4 bytes of VMEM.
+Tiling: elementwise over (rows, cols); BlockSpec tiles of TILE_R rows by
+a column tile (:func:`_col_tile`) that is the whole row, or 128 scale blocks
+when a row holds a multiple of more: Mosaic wants the last block dim of
+every operand — codes, packed words AND the per-block scales — to be a
+multiple of 128 lanes or the whole array dim.
 
 Supported: h_bits in {1,2}, n_bits in [6,16] — the paper's operating points.
 Exactness: encode of a given f32 value is bit-exact vs repro.kernels.ref
@@ -31,7 +32,8 @@ from jax.experimental import pallas as pl
 from repro.core.f2p import F2PFormat
 from repro.core.qtensor import block_scales
 from repro.kernels import dispatch
-from repro.kernels.bits import pack_bits, packed_words, unpack_bits
+from repro.kernels.bits import (pack_bits, pack_bits_mxu, packed_words,
+                                unpack_bits, unpack_bits_mxu)
 
 __all__ = ["quantize_tile_math", "dequantize_tile_math", "dequantize_lut",
            "f2p_quantize_pallas", "f2p_dequantize_pallas",
@@ -39,9 +41,8 @@ __all__ = ["quantize_tile_math", "dequantize_tile_math", "dequantize_lut",
            "f2p_quantize_packed_pallas", "f2p_dequantize_packed_pallas",
            "f2p_quantize_packed_xla", "f2p_dequantize_packed_xla"]
 
-# Default tile: 8 sublanes x 512 lanes of f32 = 16 KiB in, 4 KiB codes out.
+# Row tile: 8 sublanes.
 TILE_R = 8
-TILE_C = 512
 
 
 def _exp2i(n: jnp.ndarray) -> jnp.ndarray:
@@ -209,9 +210,16 @@ def _grid2d(shape, tr, tc):
     return (r // tr, c // tc)
 
 
+def _col_tile(c: int, block: int) -> int:
+    """Column tile for a row of ``c`` elements: 128 scale blocks when the row
+    holds a multiple of more (every operand's block stays lane-dense), else
+    the whole row (blocks then span the array's last dim)."""
+    t = 128 * block
+    return t if c > t and c % t == 0 else c
+
+
 def f2p_quantize_pallas(x: jnp.ndarray, fmt: F2PFormat, *, block: int = 128,
-                        scale_mode: str = "f32", interpret: bool | None = None,
-                        tile_r: int = TILE_R, tile_c: int = TILE_C):
+                        scale_mode: str = "f32", interpret: bool | None = None):
     """Blocked F2P quantization of a 2D array. Returns (codes, scales).
 
     ``interpret=None`` resolves via the dispatch registry: compiled on TPU,
@@ -219,19 +227,17 @@ def f2p_quantize_pallas(x: jnp.ndarray, fmt: F2PFormat, *, block: int = 128,
     if interpret is None:
         interpret = dispatch.pallas_variant() == dispatch.PALLAS_INTERPRET
     return _quantize_pallas_jit(x, fmt, block=block, scale_mode=scale_mode,
-                                interpret=bool(interpret), tile_r=tile_r,
-                                tile_c=tile_c)
+                                interpret=bool(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("fmt", "block", "scale_mode",
-                                             "interpret", "tile_r", "tile_c"))
+                                             "interpret"))
 def _quantize_pallas_jit(x: jnp.ndarray, fmt: F2PFormat, *, block: int,
-                         scale_mode: str, interpret: bool,
-                         tile_r: int, tile_c: int):
+                         scale_mode: str, interpret: bool):
     r, c = x.shape
-    tile_c = min(tile_c, c)
-    tile_r = min(tile_r, r)
-    assert c % block == 0 and tile_c % block == 0
+    tile_c = _col_tile(c, block)
+    tile_r = min(TILE_R, r)
+    assert c % block == 0
     grid = _grid2d((r, c), tile_r, tile_c)
     code_dtype = jnp.uint8 if fmt.n_bits <= 8 else jnp.uint16
     codes, scales = pl.pallas_call(
@@ -253,26 +259,23 @@ def _quantize_pallas_jit(x: jnp.ndarray, fmt: F2PFormat, *, block: int,
 
 def f2p_dequantize_pallas(codes: jnp.ndarray, scales: jnp.ndarray,
                           fmt: F2PFormat, *, block: int = 128,
-                          out_dtype=jnp.float32, interpret: bool | None = None,
-                          tile_r: int = TILE_R, tile_c: int = TILE_C):
+                          out_dtype=jnp.float32, interpret: bool | None = None):
     """Blocked F2P dequantization. ``interpret=None`` resolves via dispatch."""
     if interpret is None:
         interpret = dispatch.pallas_variant() == dispatch.PALLAS_INTERPRET
     return _dequantize_pallas_jit(codes, scales, fmt, block=block,
                                   out_dtype=out_dtype,
-                                  interpret=bool(interpret),
-                                  tile_r=tile_r, tile_c=tile_c)
+                                  interpret=bool(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("fmt", "block", "out_dtype",
-                                             "interpret", "tile_r", "tile_c"))
+                                             "interpret"))
 def _dequantize_pallas_jit(codes: jnp.ndarray, scales: jnp.ndarray,
                            fmt: F2PFormat, *, block: int,
-                           out_dtype, interpret: bool,
-                           tile_r: int, tile_c: int):
+                           out_dtype, interpret: bool):
     r, c = codes.shape
-    tile_c = min(tile_c, c)
-    tile_r = min(tile_r, r)
+    tile_c = _col_tile(c, block)
+    tile_r = min(TILE_R, r)
     grid = _grid2d((r, c), tile_r, tile_c)
     out = pl.pallas_call(
         functools.partial(_dequant_kernel, fmt, block, out_dtype),
@@ -325,16 +328,16 @@ def f2p_dequantize_xla(codes: jnp.ndarray, scales: jnp.ndarray,
 # codes occupies exactly packed_words(tile_c, n_bits) uint32 words, which is
 # word-exact either when the row fits one tile (tile_c == c: the trailing
 # slack words belong to the tile) or when tile_c is a multiple of 32
-# (tile_c * n_bits ≡ 0 mod 32 for every n_bits) — the default TILE_C = 512
-# satisfies the latter, and _packed_tiles() enforces it.
+# (tile_c * n_bits ≡ 0 mod 32 for every n_bits) — _col_tile's multi-tile
+# width, 128 * block, is one whenever the block is.
 # ---------------------------------------------------------------------------
-def _packed_tiles(c: int, tile_c: int, n_bits: int) -> tuple[int, int]:
+def _packed_tiles(c: int, block: int, n_bits: int) -> tuple[int, int]:
     """(code tile width, word tile width) for a row of ``c`` codes."""
-    tile_c = min(tile_c, c)
-    if tile_c != c and (tile_c % 32 != 0 or c % tile_c != 0):
+    tile_c = _col_tile(c, block)
+    if tile_c != c and tile_c % 32 != 0:
         raise ValueError(
-            f"packed tiling needs tile_c % 32 == 0 dividing c (got tile_c="
-            f"{tile_c}, c={c}) so tile boundaries stay word-aligned")
+            f"packed tiling needs a column tile % 32 == 0 (got {tile_c}, "
+            f"c={c}) so tile boundaries stay word-aligned")
     return tile_c, packed_words(tile_c, n_bits)
 
 
@@ -345,7 +348,7 @@ def _quant_packed_kernel(fmt: F2PFormat, block: int, scale_mode: str,
     xb = x.reshape(r, ccols // block, block)
     scale = _block_scales(xb, fmt, scale_mode)
     y = (xb / scale[..., None]).astype(jnp.float32).reshape(r, ccols)
-    words_ref[...] = pack_bits(quantize_tile_math(y, fmt), fmt.n_bits)
+    words_ref[...] = pack_bits_mxu(quantize_tile_math(y, fmt), fmt.n_bits)
     scales_ref[...] = scale
 
 
@@ -354,7 +357,8 @@ def _dequant_packed_kernel(fmt: F2PFormat, block: int, out_dtype,
     scales = scales_ref[...]
     r, nblk = scales.shape
     ccols = nblk * block
-    codes = unpack_bits(words_ref[...], fmt.n_bits, ccols).astype(jnp.int32)
+    codes = unpack_bits_mxu(words_ref[...], fmt.n_bits,
+                            ccols).astype(jnp.int32)
     vals = dequantize_tile_math(codes, fmt, jnp.float32)
     vals = vals.reshape(r, nblk, block) * scales[..., None]
     out_ref[...] = vals.reshape(r, ccols).astype(out_dtype)
@@ -362,27 +366,24 @@ def _dequant_packed_kernel(fmt: F2PFormat, block: int, out_dtype,
 
 def f2p_quantize_packed_pallas(x: jnp.ndarray, fmt: F2PFormat, *,
                                block: int = 128, scale_mode: str = "f32",
-                               interpret: bool | None = None,
-                               tile_r: int = TILE_R, tile_c: int = TILE_C):
+                               interpret: bool | None = None):
     """Blocked F2P quantization straight into packed words: (words, scales).
     Bitwise: ``pack_bits(f2p_quantize_pallas(x)[0])``."""
     if interpret is None:
         interpret = dispatch.pallas_variant() == dispatch.PALLAS_INTERPRET
     return _quantize_packed_pallas_jit(x, fmt, block=block,
                                        scale_mode=scale_mode,
-                                       interpret=bool(interpret),
-                                       tile_r=tile_r, tile_c=tile_c)
+                                       interpret=bool(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("fmt", "block", "scale_mode",
-                                             "interpret", "tile_r", "tile_c"))
+                                             "interpret"))
 def _quantize_packed_pallas_jit(x: jnp.ndarray, fmt: F2PFormat, *, block: int,
-                                scale_mode: str, interpret: bool,
-                                tile_r: int, tile_c: int):
+                                scale_mode: str, interpret: bool):
     r, c = x.shape
-    tile_c, tile_w = _packed_tiles(c, tile_c, fmt.n_bits)
-    tile_r = min(tile_r, r)
-    assert c % block == 0 and tile_c % block == 0
+    assert c % block == 0
+    tile_c, tile_w = _packed_tiles(c, block, fmt.n_bits)
+    tile_r = min(TILE_R, r)
     grid = _grid2d((r, c), tile_r, tile_c)
     W = grid[1] * tile_w
     words, scales = pl.pallas_call(
@@ -405,27 +406,24 @@ def _quantize_packed_pallas_jit(x: jnp.ndarray, fmt: F2PFormat, *, block: int,
 def f2p_dequantize_packed_pallas(words: jnp.ndarray, scales: jnp.ndarray,
                                  fmt: F2PFormat, *, block: int = 128,
                                  out_dtype=jnp.float32,
-                                 interpret: bool | None = None,
-                                 tile_r: int = TILE_R, tile_c: int = TILE_C):
+                                 interpret: bool | None = None):
     """Fused unpack-dequantize of packed words (word tiles stream to VMEM,
     codes exist only in-register)."""
     if interpret is None:
         interpret = dispatch.pallas_variant() == dispatch.PALLAS_INTERPRET
     return _dequantize_packed_pallas_jit(words, scales, fmt, block=block,
                                          out_dtype=out_dtype,
-                                         interpret=bool(interpret),
-                                         tile_r=tile_r, tile_c=tile_c)
+                                         interpret=bool(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("fmt", "block", "out_dtype",
-                                             "interpret", "tile_r", "tile_c"))
+                                             "interpret"))
 def _dequantize_packed_pallas_jit(words: jnp.ndarray, scales: jnp.ndarray,
                                   fmt: F2PFormat, *, block: int,
-                                  out_dtype, interpret: bool,
-                                  tile_r: int, tile_c: int):
+                                  out_dtype, interpret: bool):
     r, c = scales.shape[0], scales.shape[1] * block
-    tile_c, tile_w = _packed_tiles(c, tile_c, fmt.n_bits)
-    tile_r = min(tile_r, r)
+    tile_c, tile_w = _packed_tiles(c, block, fmt.n_bits)
+    tile_r = min(TILE_R, r)
     grid = _grid2d((r, c), tile_r, tile_c)
     out = pl.pallas_call(
         functools.partial(_dequant_packed_kernel, fmt, block, out_dtype),

@@ -38,6 +38,9 @@ from repro.models.sharding import logical_rules
 from repro.optim import AdamWConfig, CompressionConfig
 from repro.train import make_train_step
 
+# the chip the production meshes model (v5e pods); keys roofline.PEAKS
+TARGET_KIND = "TPU v5 lite"
+
 
 def _sharding_fn(mesh, rules):
     def fn(axes):
@@ -124,7 +127,8 @@ def run_cell(arch: str, shape_name: str, mesh, out_dir: str | None, **kw):
         compiled, cfg, meta = lower_cell(arch, shape_name, mesh, cfg=cfg, **kw)
         rl = RL.analyze(compiled, arch=arch, shape=shape_name,
                         mesh_name=mesh_name, n_devices=mesh.devices.size,
-                        cfg=cfg, seq=seq, gbatch=gbatch, kind=kind)
+                        device_kind=TARGET_KIND, cfg=cfg, seq=seq,
+                        gbatch=gbatch, kind=kind)
         rec = {**meta, **rl.to_dict(), "status": "ok",
                "compile_s": round(time.time() - t0, 1)}
         _write(out_dir, tag, rec)

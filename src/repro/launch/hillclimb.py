@@ -19,7 +19,7 @@ import time
 
 from repro.configs import SHAPES, full_config
 from repro.launch import roofline as RL
-from repro.launch.dryrun import lower_cell
+from repro.launch.dryrun import TARGET_KIND, lower_cell
 from repro.launch.mesh import make_production_mesh
 
 KNOBS = {
@@ -57,7 +57,8 @@ def run(arch, shape, variant: str, out_dir: str, quantized_kv=False):
     compiled, cfg, meta = lower_cell(arch, shape, mesh, cfg=cfg,
                                      quantized_kv=quantized_kv)
     rl = RL.analyze(compiled, arch=arch, shape=shape, mesh_name="16x16",
-                    n_devices=mesh.devices.size, cfg=cfg, seq=seq,
+                    n_devices=mesh.devices.size,
+                    device_kind=TARGET_KIND, cfg=cfg, seq=seq,
                     gbatch=gbatch, kind=kind)
     rec = {**rl.to_dict(), "variant": variant or "baseline",
            "quantized_kv": quantized_kv,

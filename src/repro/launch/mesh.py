@@ -13,20 +13,17 @@ from __future__ import annotations
 import jax
 
 
-def compat_make_mesh(shape, axes):
-    """jax.make_mesh across jax versions: ``axis_types`` (and
-    ``jax.sharding.AxisType``) only exist on newer jax; older releases default
-    to Auto axes anyway, so just omit the argument there."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(shape, axes,
-                             axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with Auto axes over ``devices`` (default: all)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def data_axes(mesh) -> tuple[str, ...]:
@@ -37,7 +34,7 @@ def data_axes(mesh) -> tuple[str, ...]:
 def make_host_mesh(n: int | None = None, name: str = "data"):
     """Small helper mesh over whatever devices exist (tests/examples)."""
     devs = jax.devices() if n is None else jax.devices()[:n]
-    return compat_make_mesh((len(devs),), (name,))
+    return make_mesh((len(devs),), (name,), devices=devs)
 
 
 def make_sketch_mesh(n: int | None = None):

@@ -1,11 +1,12 @@
 """Roofline term extraction from a compiled (SPMD-partitioned) executable.
 
-Hardware model: TPU v5e — 197 TFLOP/s bf16 per chip, 819 GB/s HBM,
-~50 GB/s/link ICI.
+Hardware model: the target chip's peaks, looked up by ``device_kind``.
 
-  compute term    = HLO_FLOPs_per_device / PEAK_FLOPS
-  memory term     = HLO_bytes_per_device / HBM_BW
-  collective term = collective_bytes_per_device / LINK_BW
+  compute term    = HLO_FLOPs_per_device / peak FLOP/s
+  memory term     = HLO_bytes_per_device / peak HBM bytes/s
+  collective term = collective_bytes_per_device / peak link bytes/s
+
+(peaks per ``device_kind``: the PEAKS table)
 
 cost_analysis() on the compiled executable is already per-partition (the
 SPMD module of one device). collective_bytes comes from parsing the
@@ -27,9 +28,22 @@ from __future__ import annotations
 import dataclasses
 import re
 
-PEAK_FLOPS = 197e12       # bf16 / chip
-HBM_BW = 819e9            # bytes/s / chip
-LINK_BW = 50e9            # bytes/s / link
+# Per-chip peaks keyed by ``jax.Device.device_kind``. TPU v5e: Google Cloud
+# documentation, "TPU v5e" (197 TFLOP/s bf16, 819 GB/s HBM; 1,600 Gbit/s of
+# interconnect over four ICI links = 50 GB/s per link). A device that is
+# not in the table is an error, not a default.
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "link_bw": 50e9},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    """(flops, hbm_bw, link_bw) peaks of one chip of ``device_kind``."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no peak table for device kind {device_kind!r}; "
+                         f"known: {sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -111,6 +125,7 @@ class Roofline:
     shape: str
     mesh: str
     n_devices: int
+    device_kind: str            # target chip; keys the PEAKS table
     hlo_flops: float            # per device
     hlo_bytes: float            # per device
     collective_bytes: float     # per device, ring model
@@ -121,15 +136,15 @@ class Roofline:
 
     @property
     def t_compute(self):
-        return self.hlo_flops / PEAK_FLOPS
+        return self.hlo_flops / peaks(self.device_kind)["flops"]
 
     @property
     def t_memory(self):
-        return self.hlo_bytes / HBM_BW
+        return self.hlo_bytes / peaks(self.device_kind)["hbm_bw"]
 
     @property
     def t_collective(self):
-        return self.collective_bytes / LINK_BW
+        return self.collective_bytes / peaks(self.device_kind)["link_bw"]
 
     @property
     def bottleneck(self):
@@ -146,7 +161,8 @@ class Roofline:
     def roofline_fraction(self):
         """Fraction of the dominant-term-bound step time that is useful
         compute: (model_flops / chips / peak) / max(term)."""
-        ideal = self.model_flops / self.n_devices / PEAK_FLOPS
+        ideal = (self.model_flops / self.n_devices
+                 / peaks(self.device_kind)["flops"])
         t = max(self.t_compute, self.t_memory, self.t_collective)
         return ideal / t if t else 0.0
 
@@ -179,8 +195,8 @@ def model_flops(cfg, shape_name: str, seq: int, gbatch: int, kind: str) -> float
     return 2.0 * n * gbatch  # decode: one token per sequence
 
 
-def analyze(compiled, *, arch, shape, mesh_name, n_devices, cfg, seq, gbatch,
-            kind) -> Roofline:
+def analyze(compiled, *, arch, shape, mesh_name, n_devices, device_kind, cfg,
+            seq, gbatch, kind) -> Roofline:
     """Terms from the trip-count-aware HLO analysis (launch.hlo_analysis).
 
     XLA's own cost_analysis counts while bodies ONCE (a scan-over-layers
@@ -196,13 +212,11 @@ def analyze(compiled, *, arch, shape, mesh_name, n_devices, cfg, seq, gbatch,
                   "generated_code_size_in_bytes"):
             memd[k] = getattr(mem, k, 0)
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # older jax: one dict per device
-        ca = ca[0] if ca else {}
     memd["xla_flops_body_once"] = float(ca.get("flops", 0.0))
     a = analyze_hlo(compiled.as_text(), n_devices)
     return Roofline(
         arch=arch, shape=shape, mesh=mesh_name, n_devices=n_devices,
-        hlo_flops=float(a["flops"]),
+        device_kind=device_kind, hlo_flops=float(a["flops"]),
         hlo_bytes=float(a["hbm_bytes"]),
         collective_bytes=float(a["ring_bytes"]),
         collective_bytes_naive=float(a["naive_bytes"]),

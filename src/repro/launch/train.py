@@ -9,8 +9,9 @@ resumes from the last committed step — on a DIFFERENT mesh shape if needed
     PYTHONPATH=src python -m repro.launch.train --arch xlstm_125m \
         --steps 100 --mesh-shape 2,2 --ckpt-dir /tmp/run1
 
-On the CPU container this runs real (reduced) configs on forced host
-devices; on TPU the same script runs the full configs unchanged.
+The mesh spans the first data*model devices. On the CPU, tests give it
+virtual host devices via XLA_FLAGS=--xla_force_host_platform_device_count;
+on TPU the same script runs the full configs unchanged.
 """
 import argparse
 import os
@@ -25,7 +26,7 @@ def main():
     ap.add_argument("--global-batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--mesh-shape", default="1,1",
-                    help="data,model (forced host devices)")
+                    help="data,model mesh over the first data*model devices")
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train_ckpt")
     ap.add_argument("--ckpt-every", type=int, default=20)
     ap.add_argument("--die-at-step", type=int, default=-1,
@@ -34,12 +35,13 @@ def main():
     args = ap.parse_args()
 
     shape = tuple(int(x) for x in args.mesh_shape.split(","))
-    ndev = shape[0] * shape[1]
-    os.environ.setdefault(
-        "XLA_FLAGS", f"--xla_force_host_platform_device_count={ndev}")
 
     import jax
     import jax.numpy as jnp
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from repro.configs import full_config, smoke_config
     from repro.data import DataConfig, host_batch
@@ -62,9 +64,10 @@ def main():
     dcfg = DataConfig(vocab_size=cfg.vocab_size, seq_len=args.seq,
                       global_batch=args.global_batch)
 
-    from repro.launch.mesh import compat_make_mesh
+    from repro.launch.mesh import make_mesh
 
-    mesh = compat_make_mesh(shape, ("data", "model"))
+    mesh = make_mesh(shape, ("data", "model"),
+                     devices=jax.devices()[:shape[0] * shape[1]])
     rules = rules_for(cfg, mesh, "train_4k")
     print(f"mesh {dict(mesh.shape)}  arch {cfg.name} "
           f"({cfg.param_count()/1e6:.1f}M params)")
@@ -91,6 +94,12 @@ def main():
         ckpt = AsyncCheckpointer(args.ckpt_dir, keep=3, policy=policy)
         for step in range(start, args.steps):
             if step == args.die_at_step:
+                # a preemption notice's grace period lets the checkpoint
+                # already in flight commit; then the process dies hard (no
+                # final save), so the restart resumes from the last step
+                # enqueued before the notice, not from whichever write
+                # happened to win the race against the exit
+                ckpt.wait()
                 print(f"SIMULATED PREEMPTION at step {step}", flush=True)
                 os._exit(42)
             batch = host_batch(dcfg, step)

@@ -239,7 +239,7 @@ def attention_apply(params, x, cfg, *, mode: str, cache=None, pos_offset=0,
 
     ``pages`` (decode only): a ``[B, max_pages]`` int32 page table. When set,
     ``cache`` is a pool SLAB (``{"k","v"}`` QTensors, codes
-    ``[n_pages, page_tokens, K, words]``) instead of a dense per-row cache:
+    ``[n_pages, page_tokens, K*words]``) instead of a dense per-row cache:
     the new token's KV is quantized and scattered into the slab page holding
     position ``pos_offset`` and attention reads word tiles straight through
     the table (``attention_paged``) — no dense ``[B, max_seq]`` row exists
@@ -403,9 +403,11 @@ def _paged_cache_write(cache, k, v, pos, pages):
 
     def wr(qt: QTensor, x) -> QTensor:
         up = quantize_kv(x, qt.fmt, packed=True)          # [B, 1, K, *]
+        B = up.codes.shape[0]
+        # slab rows hold every kv head side by side ([..., K*words])
         return QTensor.from_parts(
-            qt.codes.at[pidx, off].set(up.codes[:, 0]),
-            qt.scales.at[pidx, off].set(up.scales[:, 0]),
+            qt.codes.at[pidx, off].set(up.codes.reshape(B, -1)),
+            qt.scales.at[pidx, off].set(up.scales.reshape(B, -1)),
             qt.fmt, qt.block, qt.shape, packed=qt.packed)
 
     return {"k": wr(cache["k"], k), "v": wr(cache["v"], v)}

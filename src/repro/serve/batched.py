@@ -151,6 +151,15 @@ def _pages_delta(pages, mask, pages_n):
     return jnp.where(mask[:, None], pages_n, pages)
 
 
+def _upload(mirror: np.ndarray):
+    """Device copy of a host mirror. The mirrors and dirty masks are mutated
+    in place right after the upload is dispatched, and the host-to-device
+    copy of a numpy array may still be in flight then: on the CPU backend a
+    cleared mask leaked into in-flight uploads (slots decoding a stale token
+    under load). Uploading a private copy removes the race."""
+    return jnp.asarray(mirror.copy())
+
+
 class BatchedEngine:
     """Continuous-batching engine; see module docstring. ``run(requests)``
     returns {uid: np.int32 tokens} plus fills ``self.stats``."""
@@ -211,7 +220,7 @@ class BatchedEngine:
         self._pages_h = np.full((B, maxp), self._dump, np.int32)
         self._dirty = np.zeros((B,), bool)
         self._pages_dirty = np.zeros((B,), bool)
-        self.pages = jnp.asarray(self._pages_h) if self.paged else None
+        self.pages = _upload(self._pages_h) if self.paged else None
         # span buckets: each round attends through pages[:, :span] where
         # span is the smallest bucket covering every live slot's writes.
         # Only the page TABLE is sliced (the pool slabs never move), so
@@ -720,23 +729,23 @@ class BatchedEngine:
         if not (io.any() or pg_any):
             return
         if self.bscfg.io_upload == "full":
-            self.tok = jnp.asarray(self._tok_h[:, None])
-            self.pos = jnp.asarray(self._pos_h)
-            self.req = jnp.asarray(self._req_h)
+            self.tok = _upload(self._tok_h[:, None])
+            self.pos = _upload(self._pos_h)
+            self.req = _upload(self._req_h)
             if self.paged:
-                self.pages = jnp.asarray(self._pages_h)
+                self.pages = _upload(self._pages_h)
         else:
             # token/pos/req rows dirty only at admission boundaries; page
             # rows also go dirty every growth round — two masks, so the
             # steady decode round uploads ONE small [slots, max_pages] delta
             if io.any():
                 self.tok, self.pos, self.req = _io_delta(
-                    self.tok, self.pos, self.req, jnp.asarray(io),
-                    jnp.asarray(self._tok_h), jnp.asarray(self._pos_h),
-                    jnp.asarray(self._req_h))
+                    self.tok, self.pos, self.req, _upload(io),
+                    _upload(self._tok_h), _upload(self._pos_h),
+                    _upload(self._req_h))
             if pg_any:
-                self.pages = _pages_delta(self.pages, jnp.asarray(pg),
-                                          jnp.asarray(self._pages_h))
+                self.pages = _pages_delta(self.pages, _upload(pg),
+                                          _upload(self._pages_h))
         io[:] = False
         pg[:] = False
 

@@ -2,7 +2,13 @@
 
 The pool owns, per attention position in ``cfg.pattern`` and per k/v, one
 **slab**: a packed :class:`~repro.core.qtensor.QTensor` of logical shape
-``[G, n_pages, page_tokens, K, hd]``. A logical *page* is one index on the
+``[G, n_pages, page_tokens, K*hd]`` blocked over ``hd`` — each token's kv
+heads side by side in one row, so the codes leaf ``[..., K*words]`` is
+lane-dense on the TPU (a ``[..., K, words]`` leaf with 32-word rows would
+be padded 4x in HBM). The word image is the per-head ``[..., K, words]``
+cache row's, reshaped: hd*n_bits is a multiple of 32, so per-head rows
+concatenate into the packed K*hd row bit for bit. A logical *page* is one
+index on the
 page axis — the same index across every slab — holding ``page_tokens``
 consecutive cache positions of every layer at once, so a request's KV is
 described by a single ordered page list (:class:`PageTable`) plus its live
@@ -80,7 +86,7 @@ def _store_row_all(slab_parts, cache_parts, pages, row):
         size = (G, 1, n * T) + leaf.shape[3:]
         start = (jnp.int32(0), row) + (jnp.int32(0),) * (leaf.ndim - 2)
         blk = jax.lax.dynamic_slice(leaf, start, size).reshape(
-            (G, n, T) + leaf.shape[3:])
+            (G, n, T) + slab.shape[3:])
         return slab.at[:, pages].set(blk)
 
     return jax.tree.map(one, slab_parts, cache_parts)
@@ -95,7 +101,7 @@ def _load_row_all(slab_parts, cache_parts, pages, row):
     def one(slab, leaf):
         G, T = slab.shape[0], slab.shape[2]
         blk = jnp.take(slab, pages, axis=1).reshape(
-            (G, 1, n * T) + slab.shape[3:])
+            (G, 1, n * T) + leaf.shape[3:])
         start = (jnp.int32(0), row) + (jnp.int32(0),) * (leaf.ndim - 2)
         return jax.lax.dynamic_update_slice(leaf, blk, start)
 
@@ -143,16 +149,18 @@ class PagedKVPool:
             if kv_policy is not None:
                 fmt, _ = kv_policy.f2p_for(f"kv/{key}", (fmt, 0))
             zero_code = int(fmt.encode_nearest(np.zeros(1))[0])
-            row = pack_bits_np(np.full((hd,), zero_code, np.uint32),
+            if hd * fmt.n_bits % 32:
+                raise ValueError(
+                    f"head_dim {hd} x {fmt.n_bits} bits is not whole words")
+            row = pack_bits_np(np.full((K * hd,), zero_code, np.uint32),
                                fmt.n_bits)
-            shape = (G, n_pages, page_tokens, K, hd)
+            shape = (G, n_pages, page_tokens, K * hd)
             # one MATERIALIZED buffer per (k/v, leaf): slab ops donate their
             # buffers, so k and v must never alias the same storage
             self.slabs[key] = {
                 kv: QTensor.from_parts(
-                    jnp.tile(jnp.asarray(row),
-                             (G, n_pages, page_tokens, K, 1)),
-                    jnp.ones((G, n_pages, page_tokens, K, 1), jnp.float32),
+                    jnp.tile(jnp.asarray(row), (G, n_pages, page_tokens, 1)),
+                    jnp.ones((G, n_pages, page_tokens, K), jnp.float32),
                     fmt, hd, shape, packed=True)
                 for kv in ("k", "v")}
 

@@ -25,9 +25,10 @@ FORMATS = [F2PFormat(6, 2, Flavor.SR, signed=True),
 
 
 def _slab(seed, P=11, T=8, K=2, hd=32, fmt=FORMATS[1]):
-    """A pool-slab-shaped packed QTensor [P, T, K, hd] of random KV."""
+    """A pool slab of random KV: packed QTensor [P, T, K*hd] blocked over
+    hd (codes [P, T, K*words], scales [P, T, K])."""
     rng = np.random.default_rng(seed)
-    x = jnp.asarray(rng.normal(size=(P, T, K, hd)).astype(np.float32))
+    x = jnp.asarray(rng.normal(size=(P, T, K * hd)).astype(np.float32))
     return QT.quantize(x, fmt, block=hd, packed=True, backend="xla")
 
 
@@ -79,12 +80,12 @@ def test_garbage_pages_beyond_kv_len_contribute_zero(backend):
     fmt = FORMATS[1]
     q, kq, vq, pages = _case(2, B=2, P=8, maxp=4)  # live ids only in 0..7
     kq = QT.QTensor.from_parts(          # widen the slabs by a 9th page (id
-        jnp.pad(kq.codes, ((0, 1),) + ((0, 0),) * 3),   # 8) no row lives in
-        jnp.pad(kq.scales, ((0, 1),) + ((0, 0),) * 3),
+        jnp.pad(kq.codes, ((0, 1),) + ((0, 0),) * 2),   # 8) no row lives in
+        jnp.pad(kq.scales, ((0, 1),) + ((0, 0),) * 2),
         kq.fmt, kq.block, (9,) + tuple(kq.shape[1:]), packed=True)
     vq = QT.QTensor.from_parts(
-        jnp.pad(vq.codes, ((0, 1),) + ((0, 0),) * 3),
-        jnp.pad(vq.scales, ((0, 1),) + ((0, 0),) * 3),
+        jnp.pad(vq.codes, ((0, 1),) + ((0, 0),) * 2),
+        jnp.pad(vq.scales, ((0, 1),) + ((0, 0),) * 2),
         vq.fmt, vq.block, (9,) + tuple(vq.shape[1:]), packed=True)
     kv_len = jnp.asarray([19, 9], jnp.int32)       # rows live in pages 0..2
     base = FA.attention_paged(q, kq, vq, pages, kv_len=kv_len,
@@ -95,7 +96,7 @@ def test_garbage_pages_beyond_kv_len_contribute_zero(backend):
     pg = np.asarray(pages).copy()
     dead_mask = np.arange(pg.shape[1])[None, :] >= live
     pg[dead_mask] = 8                              # the garbage page id
-    big = jnp.full((1, 8, 2, 32), 1e9, jnp.float32)
+    big = jnp.full((1, 8, 2 * 32), 1e9, jnp.float32)
     bigq = QT.quantize(big, fmt, block=32, packed=True, backend="xla")
     kq2 = QT.QTensor.from_parts(
         kq.codes.at[8].set(bigq.codes[0]), kq.scales.at[8].set(bigq.scales[0]),
@@ -124,10 +125,10 @@ def test_gather_pages_to_dense_is_pure_word_copy():
     for b in range(2):
         for j, p in enumerate(np.asarray(pages)[b]):
             np.testing.assert_array_equal(
-                np.asarray(dense.codes[b, j * 8:(j + 1) * 8]),
+                np.asarray(dense.codes[b, j * 8:(j + 1) * 8]).reshape(8, -1),
                 np.asarray(kq.codes[p]))
             np.testing.assert_array_equal(
-                np.asarray(dense.scales[b, j * 8:(j + 1) * 8]),
+                np.asarray(dense.scales[b, j * 8:(j + 1) * 8]).reshape(8, -1),
                 np.asarray(kq.scales[p]))
 
 

@@ -42,6 +42,8 @@ def test_default_resolution_matches_platform(monkeypatch):
 
 
 def test_resolution_inside_trace_is_xla_and_trace_safe(monkeypatch):
+    """Inside a trace the policy is the platform's: compiled pallas on a
+    TPU (the jitted serving round must run the kernels), xla elsewhere."""
     monkeypatch.delenv("F2P_BACKEND", raising=False)
     seen = []
 
@@ -51,7 +53,32 @@ def test_resolution_inside_trace_is_xla_and_trace_safe(monkeypatch):
         return x
 
     f(jnp.zeros(()))
-    assert seen == ["xla"]
+    assert seen == ["pallas" if jax.default_backend() == "tpu" else "xla"]
+
+
+@pytest.mark.parametrize("platform,op,want", [
+    ("tpu", "attention_paged", "pallas"),
+    ("tpu", "quantize_packed", "pallas"),
+    ("tpu", "only_xla_op_on_tpu", "xla"),
+    ("cpu", "attention_paged", "xla"),
+])
+def test_trace_resolution_by_platform(monkeypatch, platform, op, want):
+    """The rule itself, platform stubbed: in a trace, an op with a Pallas
+    kernel resolves to compiled pallas on a TPU; an op without one, and
+    every op off the TPU, resolves to xla."""
+    from repro.kernels import f2p_attention, f2p_quant  # noqa: F401
+
+    dispatch.register("only_xla_op_on_tpu", "xla")(lambda: None)
+    monkeypatch.delenv("F2P_BACKEND", raising=False)
+    monkeypatch.setattr(dispatch.jax, "default_backend", lambda: platform)
+    seen = []
+
+    def f(x):
+        seen.append(dispatch.resolve_backend(op=op))
+        return x
+
+    jax.make_jaxpr(f)(jnp.zeros(()))
+    assert seen == [want]
 
 
 def test_env_override(monkeypatch):

@@ -82,16 +82,8 @@ def test_scan_memory_not_billed_full_buffer():
 
 
 def test_collectives_inside_scan_multiplied():
-    import inspect
-
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:          # older jax
-        from jax.experimental.shard_map import shard_map
-    smkw = ({"check_vma": False}
-            if "check_vma" in inspect.signature(shard_map).parameters
-            else {"check_rep": False})
 
     mesh = Mesh(np.array(jax.devices()[:1]), ("d",))
 
@@ -101,7 +93,8 @@ def test_collectives_inside_scan_multiplied():
     def f(c, xs):
         return jax.lax.scan(step, c, xs)[0]
 
-    g = shard_map(f, mesh=mesh, in_specs=(P(), P()), out_specs=P(), **smkw)
+    g = shard_map(f, mesh=mesh, in_specs=(P(), P()), out_specs=P(),
+                  check_vma=False)
     c = jnp.ones((64, 64), jnp.float32)
     xs = jnp.ones((7, 64, 64), jnp.float32)
     txt = jax.jit(g).lower(c, xs).compile().as_text()

@@ -14,11 +14,10 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def test_sanitize_spec_drops_nondivisible():
+    from repro.launch.mesh import make_mesh
     from repro.launch.shardings import sanitize_spec
 
-    from repro.launch.mesh import compat_make_mesh
-
-    mesh = compat_make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
 
     # fake a 16-wide axis via a mesh dict stub
     class M:
@@ -48,9 +47,21 @@ def test_model_flops_kinds():
     assert d == pytest.approx(2 * active_params(cfg) * 128)
 
 
+def test_roofline_peaks_keyed_by_device_kind():
+    from repro.launch.roofline import peaks
+
+    assert peaks("TPU v5 lite")["flops"] == 197e12
+    r = Roofline(arch="a", shape="s", mesh="m", n_devices=1,
+                 device_kind="cpu", hlo_flops=1.0, hlo_bytes=1.0,
+                 collective_bytes=0.0, collective_bytes_naive=0,
+                 model_flops=1.0, memory_per_device={}, per_op={})
+    with pytest.raises(ValueError, match="no peak table"):
+        r.t_compute
+
+
 def test_roofline_properties():
     r = Roofline(arch="a", shape="s", mesh="m", n_devices=256,
-                 hlo_flops=197e12, hlo_bytes=819e9 * 2,
+                 device_kind="TPU v5 lite", hlo_flops=197e12, hlo_bytes=819e9 * 2,
                  collective_bytes=50e9 * 3, collective_bytes_naive=0,
                  model_flops=197e12 * 256 * 0.5, memory_per_device={},
                  per_op={})
@@ -85,8 +96,8 @@ from repro.configs import smoke_config
 from repro.launch import roofline as RL
 from repro.launch.dryrun import lower_cell
 from repro.launch.shardings import rules_for
-from repro.launch.mesh import compat_make_mesh
-mesh = compat_make_mesh((2, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2), ("data", "model"))
 import repro.configs.registry as REG
 # mutate in place: the dict object is shared across module bindings
 REG.SHAPES["train_4k"] = (64, 4, "train")
@@ -95,7 +106,8 @@ for shape in ("train_4k", "decode_32k"):
     compiled, cfg, meta = lower_cell("llama4_scout_17b", shape, mesh,
                                      cfg=smoke_config("llama4_scout_17b"))
     rl = RL.analyze(compiled, arch="scout-smoke", shape=shape,
-                    mesh_name="2x2", n_devices=4, cfg=cfg, seq=64, gbatch=4,
+                    mesh_name="2x2", n_devices=4, device_kind="TPU v5 lite",
+                    cfg=cfg, seq=64, gbatch=4,
                     kind=REG.SHAPES[shape][2])
     assert rl.hlo_flops > 0, shape
     assert rl.t_memory > 0, shape

@@ -308,15 +308,11 @@ def test_checkpoint_packed_6bit_shrinks(tmp_path):
 
 
 def test_compressed_psum_packed_parity():
+    from jax import shard_map as smap
     from jax.sharding import Mesh, PartitionSpec as P
 
     from repro.optim.compress import CompressionConfig, compressed_psum
 
-    try:
-        from jax import shard_map as _sm
-        smap = _sm.shard_map if hasattr(_sm, "shard_map") else _sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as smap
     mesh = Mesh(np.array(jax.devices()[:1]), ("dp",))
     g = _data((64, 192), seed=5, scale=1e-3)
     outs = {}

@@ -69,16 +69,9 @@ def test_pytree_roundtrip_under_jit():
 
 
 def test_pytree_roundtrip_under_shard_map():
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-    import inspect
 
-    kw = ({"check_vma": False}
-          if "check_vma" in inspect.signature(shard_map).parameters
-          else {"check_rep": False})
     mesh = Mesh(np.array(jax.devices()[:1]), ("d",))
     x = _data((8, 256))
 
@@ -86,7 +79,8 @@ def test_pytree_roundtrip_under_shard_map():
         qt = QT.quantize(xs, FMT8, block=128)
         return qt.dequantize()
 
-    f = jax.jit(shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(), **kw))
+    f = jax.jit(shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(),
+                          check_vma=False))
     want = QT.quantize(x, FMT8, block=128).dequantize()
     np.testing.assert_array_equal(np.asarray(f(x)), np.asarray(want))
 

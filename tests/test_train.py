@@ -153,15 +153,8 @@ def test_compressed_psum_matches_mean_8dev():
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:              # older jax
-    from jax.experimental.shard_map import shard_map
-import inspect
-_smkw = ({"check_vma": False}
-         if "check_vma" in inspect.signature(shard_map).parameters
-         else {"check_rep": False})
 from repro.optim import CompressionConfig, compressed_psum
 
 mesh = Mesh(np.array(jax.devices()), ("d",))
@@ -171,7 +164,8 @@ rng = np.random.default_rng(1)
 g = jnp.asarray(rng.normal(size=(8, 32, 64)), jnp.float32)
 
 f = jax.jit(shard_map(lambda x: compressed_psum(x[0], "d", ccfg)[None],
-                      mesh=mesh, in_specs=P("d"), out_specs=P("d"), **_smkw))
+                      mesh=mesh, in_specs=P("d"), out_specs=P("d"),
+                      check_vma=False))
 out = np.asarray(f(g))            # [8, 32, 64]: each device's result row
 exact = np.asarray(g).mean(0)
 # every device agrees
