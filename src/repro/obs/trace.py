@@ -1,5 +1,5 @@
 """Span tracer: nested timing spans + instant events on a wall-clock
-timeline, exported as Chrome/Perfetto ``trace_event`` JSON or compact JSONL.
+timeline, exported as Chrome/Perfetto ``trace_event`` JSON.
 
 The event model is the Trace Event Format subset Perfetto renders natively:
 
@@ -145,12 +145,6 @@ class SpanTracer:
     def write_chrome(self, path: str) -> None:
         with open(path, "w") as f:
             json.dump(self.to_chrome(), f)
-
-    def write_jsonl(self, path: str) -> None:
-        """Compact one-event-per-line form for grep/stream processing."""
-        with open(path, "w") as f:
-            for ev in self._events:
-                f.write(json.dumps(ev, separators=(",", ":")) + "\n")
 
     def summary(self) -> dict:
         """Per-name aggregate (count, total µs) — what ``obs.export()``

@@ -47,6 +47,18 @@ dominate under pressure. The FIFO starvation bound is preserved as a hard
 floor: a request passed over ``preempt_patience`` times scores +inf and must
 be admitted next.
 
+Tracing (``obs.enable()``; every site is one ``is None`` probe when off):
+the engine row (tid 0) carries phase spans that tile ``run()``:
+``schedule`` (visibility stamps, readmission, admission; ``prefill`` /
+``prefill_group`` nest in it, each split into ``prefill.launch``,
+``prefill.store`` and ``prefill.wait``), ``round`` (``round.prep``: page
+growth, slab binding, mirror uploads, span slice; ``round.launch``;
+``round.wait``: the host blocked on the token chunk) and ``harvest``
+(harvest, retirement, defrag, starvation check, preemption). Counter
+events: ``slots`` per round (``active``, ``pool_used``, and the kept
+tokens' ``kv_live`` / ``kv_written`` / ``steps_kept``). :data:`PROGRAMS`
+names the engine's XLA programs.
+
 Bitwise contract (families with ``exact_cobatch``): per-request greedy
 outputs are identical to the sequential engine's — and paged decode is
 bitwise-identical to the copy-in engine — pinned by
@@ -70,7 +82,26 @@ from repro.models.config import ModelConfig
 from repro.serve.arch import SupportedArchitecture, arch_for
 from repro.serve.paging import HostKV, PagedKVPool, PageTable
 
-__all__ = ["BatchedServeConfig", "BatchedEngine", "Request"]
+__all__ = ["BatchedServeConfig", "BatchedEngine", "Request", "PROGRAMS"]
+
+# The XLA module name of every program the engine (and its page pool)
+# dispatches -> the layer a profile's device time is put down to. A device
+# op of any other module (eager argmax, converts, slices) reads as "other".
+# kv_store pages a prefill's KV into the pool; kv_move copies pages that
+# already hold KV (defrag, park/readmit, copy-in). Renaming a jitted
+# function renames its module: tests pin this table.
+PROGRAMS = {
+    "jit_round_fn": "round",
+    "jit_prefill_step": "prefill",
+    "jit__store_row_all": "kv_store",
+    "jit__load_row_all": "kv_move",
+    "jit__leaf_set_slot": "kv_move",
+    "jit__scatter_pages": "kv_move",
+    "jit__gather_pages": "kv_move",
+    "jit__move_pages_all": "kv_move",
+    "jit__io_delta": "upload",
+    "jit__pages_delta": "upload",
+}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -409,19 +440,22 @@ class BatchedEngine:
         (first tokens [n] numpy, pf_caches, lengths). Padding is
         bitwise-invisible: each row's cache and last-token logits depend
         only on that row's own positions (pinned by tests)."""
-        n = len(prompts)
-        N = self._group_size(n)
-        Ls = [int(p.shape[0]) for p in prompts]
-        toks = np.zeros((N, bucket), np.int32)
-        last = np.zeros((N,), np.int32)
-        for i, p in enumerate(prompts):
-            toks[i, :Ls[i]] = p
-            last[i] = Ls[i] - 1
-        logits, pf_caches = self._prefill(
-            self.params, jnp.asarray(toks), self._pf_template(N, bucket),
-            jnp.asarray(last, jnp.int32))
-        self._c_prefill_calls.inc()
-        tok0 = np.asarray(jnp.argmax(logits, -1).astype(jnp.int32))
+        with obs.span("prefill.launch"):
+            n = len(prompts)
+            N = self._group_size(n)
+            Ls = [int(p.shape[0]) for p in prompts]
+            toks = np.zeros((N, bucket), np.int32)
+            last = np.zeros((N,), np.int32)
+            for i, p in enumerate(prompts):
+                toks[i, :Ls[i]] = p
+                last[i] = Ls[i] - 1
+            logits, pf_caches = self._prefill(
+                self.params, jnp.asarray(toks), self._pf_template(N, bucket),
+                jnp.asarray(last, jnp.int32))
+            self._c_prefill_calls.inc()
+            tok0 = jnp.argmax(logits, -1).astype(jnp.int32)
+        with obs.span("prefill.wait"):
+            tok0 = np.asarray(tok0)
         return tok0[:n], pf_caches, Ls
 
     def _copy_recurrent(self, pf_caches, slot: int):
@@ -499,20 +533,24 @@ class BatchedEngine:
         self._note_admission(r)
         obs.instant("admit", uid=r.uid, slot=slot)
         with obs.span("prefill", uid=r.uid, L=len(r.tokens)):
-            tok0, pf_caches, L = self._prefill_request(np.asarray(r.tokens))
+            with obs.span("prefill.launch"):
+                tok0, pf_caches, L = self._prefill_request(
+                    np.asarray(r.tokens))
             table = None
-            if self.pool is not None:
-                table = self.pool.store_prefill(pf_caches, L)
-                if not self.paged:
-                    self.caches = self.pool.load_into_slot(table, self.caches,
-                                                           slot)
-                    self.pool.free(table.pages)
-                    table = None
-            if self.arch.recurrent_state:
-                self._copy_recurrent(pf_caches, slot)
+            with obs.span("prefill.store"):
+                if self.pool is not None:
+                    table = self.pool.store_prefill(pf_caches, L)
+                    if not self.paged:
+                        self.caches = self.pool.load_into_slot(
+                            table, self.caches, slot)
+                        self.pool.free(table.pages)
+                        table = None
+                if self.arch.recurrent_state:
+                    self._copy_recurrent(pf_caches, slot)
             # first token: argmax of the prefill logits, same as the
             # sequential engine — it is token 0 of the output
-            first = int(np.asarray(tok0)[0])
+            with obs.span("prefill.wait"):
+                first = int(np.asarray(tok0)[0])
         self._place(r, slot, first, L, table, results)
 
     def _admit_batch(self, pairs: list[tuple[Request, int]], results: dict):
@@ -548,14 +586,15 @@ class BatchedEngine:
         with obs.span("prefill_group", n=len(chunk), bucket=bucket):
             tok0, pf_caches, Ls = self._prefill_group(
                 [np.asarray(r.tokens) for r, _ in chunk], bucket)
-            for i, (r, s) in enumerate(chunk):
-                table = self.pool.store_prefill(pf_caches, Ls[i], row=i)
-                if not self.paged:
-                    self.caches = self.pool.load_into_slot(table, self.caches,
-                                                           s)
-                    self.pool.free(table.pages)
-                    table = None
-                self._place(r, s, int(tok0[i]), Ls[i], table, results)
+            with obs.span("prefill.store"):
+                for i, (r, s) in enumerate(chunk):
+                    table = self.pool.store_prefill(pf_caches, Ls[i], row=i)
+                    if not self.paged:
+                        self.caches = self.pool.load_into_slot(
+                            table, self.caches, s)
+                        self.pool.free(table.pages)
+                        table = None
+                    self._place(r, s, int(tok0[i]), Ls[i], table, results)
 
     def _retire(self, uid: int, n_tokens: int):
         """Fold a finished request's timing into the histograms and (when
@@ -751,34 +790,62 @@ class BatchedEngine:
 
     def _rounds(self) -> np.ndarray:
         """``sync_every`` decode steps; one [slots, sync_every] host sync."""
-        need = 0
-        if self.paged:
-            need = self._grow_tables()
-            self._bind_slabs()      # pool ops may have rebuilt slab buffers
-        self._upload_io()
-        pages = self.pages
-        if self.paged:
-            # attend only the live span: slice the page TABLE to the
-            # smallest bucket covering every live slot (the KV slabs never
-            # move, so this is one tiny device slice). Copy-in has no such
-            # lever — its dense cache row is [slots, max_seq] by layout.
-            span = next((b for b in self._span_buckets if b >= need),
-                        self._span_buckets[-1])
-            if span < pages.shape[1]:
-                pages = pages[:, :span]
-        self.tok, self.caches, self.pos, chunk_d = self._round(
-            self.params, self.caches, self.tok, self.pos, self.req,
-            pages)
-        if self.paged:
-            self._push_slabs()      # the round donated+rebuilt the slabs
-        chunk = np.asarray(chunk_d)
-        # keep the mirrors in lockstep: last emitted token is the next step
-        # input; position advances one per step, clamped exactly like the
-        # device-side jnp.minimum(pos + 1, max_seq - 1)
-        self._tok_h[:] = chunk[:, -1]
-        np.minimum(self._pos_h + self.bscfg.sync_every,
-                   self.bscfg.max_seq - 1, out=self._pos_h)
+        with obs.span("round.prep"):
+            need = 0
+            if self.paged:
+                need = self._grow_tables()
+                self._bind_slabs()  # pool ops may have rebuilt slab buffers
+            self._upload_io()
+            pages = self.pages
+            if self.paged:
+                # attend only the live span: slice the page TABLE to the
+                # smallest bucket covering every live slot (the KV slabs
+                # never move, so this is one tiny device slice). Copy-in has
+                # no such lever — its dense cache row is [slots, max_seq].
+                span = next((b for b in self._span_buckets if b >= need),
+                            self._span_buckets[-1])
+                if span < pages.shape[1]:
+                    pages = pages[:, :span]
+        with obs.span("round.launch"):
+            self.tok, self.caches, self.pos, chunk_d = self._round(
+                self.params, self.caches, self.tok, self.pos, self.req,
+                pages)
+            if self.paged:
+                self._push_slabs()  # the round donated+rebuilt the slabs
+        with obs.span("round.wait"):
+            chunk = np.asarray(chunk_d)
+            # keep the mirrors in lockstep: last emitted token is the next
+            # step input; position advances one per step, clamped exactly
+            # like the device-side jnp.minimum(pos + 1, max_seq - 1)
+            self._tok_h[:] = chunk[:, -1]
+            np.minimum(self._pos_h + self.bscfg.sync_every,
+                       self.bscfg.max_seq - 1, out=self._pos_h)
         return chunk
+
+    def _kept_work(self, chunk: np.ndarray) -> dict[str, int]:
+        """What the round just synced did for the tokens harvest will keep
+        (the same stopping rule as :meth:`_harvest`): ``kv_live``, the
+        context each kept token's step attended, summed; ``kv_written``,
+        the kept tokens (one KV row written each); ``steps_kept``, the
+        round's steps that kept a token for some live request."""
+        live = written = steps = 0
+        eos = self.bscfg.eos
+        for s, st in enumerate(self.slots):
+            if st is None:
+                continue
+            n = min(chunk.shape[1], st.max_new - len(st.tokens))
+            if eos >= 0:
+                hit = np.flatnonzero(chunk[s, :n] == eos)
+                if hit.size:
+                    n = int(hit[0]) + 1
+            # step k reads the token at position prompt_len + have - 1 + k
+            # and attends every position up to it
+            ctx0 = st.prompt_len + len(st.tokens)
+            live += n * ctx0 + n * (n - 1) // 2
+            written += n
+            steps = max(steps, n)
+        return {"kv_live": live, "kv_written": written,
+                "steps_kept": steps}
 
     def _harvest(self, chunk: np.ndarray, results: dict):
         for s, st in enumerate(self.slots):
@@ -854,26 +921,30 @@ class BatchedEngine:
         if tracing:
             obs.get().tracer.thread_name(0, "engine")
         while pending or parked or self._n_active():
-            # stamp first-visibility time on newly admissible requests (the
-            # queue-wait/TTFT clock starts when a request COULD be admitted)
-            now = time.perf_counter_ns()
-            for r in pending:
-                if r.arrival > step_no:
-                    break
-                self._rt.setdefault(r.uid, {"visible": now})
-            # admit: parked first (they hold evicted state), then arrivals
-            # picked by the SLO scheduler and batch-prefilled per bucket
-            new_slots = []
-            for s in self._free_slots():
-                if parked:
-                    self._readmit(parked.popleft(), s)
-                else:
-                    new_slots.append(s)
-            if new_slots and pending:
-                chosen = self._select_admissions(pending, step_no,
-                                                 len(new_slots))
-                if chosen:
-                    self._admit_batch(list(zip(chosen, new_slots)), results)
+            with obs.span("schedule"):
+                # stamp first-visibility time on newly admissible requests
+                # (the queue-wait/TTFT clock starts when a request COULD be
+                # admitted)
+                now = time.perf_counter_ns()
+                for r in pending:
+                    if r.arrival > step_no:
+                        break
+                    self._rt.setdefault(r.uid, {"visible": now})
+                # admit: parked first (they hold evicted state), then
+                # arrivals picked by the SLO scheduler and batch-prefilled
+                # per bucket
+                new_slots = []
+                for s in self._free_slots():
+                    if parked:
+                        self._readmit(parked.popleft(), s)
+                    else:
+                        new_slots.append(s)
+                if new_slots and pending:
+                    chosen = self._select_admissions(pending, step_no,
+                                                     len(new_slots))
+                    if chosen:
+                        self._admit_batch(list(zip(chosen, new_slots)),
+                                          results)
             if not self._n_active():
                 # idle: fast-forward the clock to the next arrival
                 if pending:
@@ -882,39 +953,42 @@ class BatchedEngine:
                 break   # only parked left with no free slot: impossible
             with obs.span("round", step=step_no):
                 chunk = self._rounds()
-            n_act = self._n_active()
-            step_no += self.bscfg.sync_every
-            self._g_steps.set(step_no)
-            self._g_active.set(n_act)
-            self._c_rounds.inc()
-            self._c_prod.inc(n_act * self.bscfg.sync_every)
-            if tracing:
-                series = {"active": n_act}
-                if self.pool is not None:
-                    series["pool_used"] = self.pool.stats()["used"]
-                obs.counter_event("slots", **series)
-            before = len(results)
-            self._harvest(chunk, results)
-            if self.bscfg.defrag_every and \
-                    self._c_rounds.exact % self.bscfg.defrag_every == 0:
-                self.compact_pool()
-            # starvation -> preempt the longest-remaining-tail slot and
-            # admit the scheduler's pick
-            waiting = (any(r.arrival <= step_no for r in pending)
-                       and not self._free_slots())
-            retired = len(results) > before
-            starve_rounds = starve_rounds + 1 if (waiting and not retired) \
-                else 0
-            if waiting and starve_rounds >= self.bscfg.preempt_patience:
-                victim = max(
-                    (s for s, st in enumerate(self.slots) if st is not None),
-                    key=lambda s: self.slots[s].max_new
-                    - len(self.slots[s].tokens))
-                parked.append(self._park_slot(victim))
-                chosen = self._select_admissions(pending, step_no, 1)
-                if chosen:
-                    self._admit_batch([(chosen[0], victim)], results)
-                starve_rounds = 0
+            with obs.span("harvest"):
+                n_act = self._n_active()
+                step_no += self.bscfg.sync_every
+                self._g_steps.set(step_no)
+                self._g_active.set(n_act)
+                self._c_rounds.inc()
+                self._c_prod.inc(n_act * self.bscfg.sync_every)
+                if tracing:
+                    series = {"active": n_act}
+                    if self.pool is not None:
+                        series["pool_used"] = self.pool.used
+                    series.update(self._kept_work(chunk))
+                    obs.counter_event("slots", **series)
+                before = len(results)
+                self._harvest(chunk, results)
+                if self.bscfg.defrag_every and \
+                        self._c_rounds.exact % self.bscfg.defrag_every == 0:
+                    self.compact_pool()
+                # starvation -> preempt the longest-remaining-tail slot and
+                # admit the scheduler's pick
+                waiting = (any(r.arrival <= step_no for r in pending)
+                           and not self._free_slots())
+                retired = len(results) > before
+                starve_rounds = starve_rounds + 1 \
+                    if (waiting and not retired) else 0
+                if waiting and starve_rounds >= self.bscfg.preempt_patience:
+                    victim = max(
+                        (s for s, st in enumerate(self.slots)
+                         if st is not None),
+                        key=lambda s: self.slots[s].max_new
+                        - len(self.slots[s].tokens))
+                    parked.append(self._park_slot(victim))
+                    chosen = self._select_admissions(pending, step_no, 1)
+                    if chosen:
+                        self._admit_batch([(chosen[0], victim)], results)
+                    starve_rounds = 0
         # flush any unfinished (shouldn't happen: harvest retires at max_new)
         for s, st in enumerate(self.slots):
             if st is not None:
