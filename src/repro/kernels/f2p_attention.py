@@ -4,22 +4,38 @@ The serving decode loop used to dequantize the WHOLE quantized cache to f32
 before every attention call (``models.attention._cache_read``), so the
 packed-storage bandwidth win of DESIGN.md §9 died at the attention boundary.
 This kernel carries the packed stream through attention: each grid step
-streams one (tile, packed_words(head_dim)) uint32 WORD tile of K and V per
-(batch, kv-head) from the cache layout ``[B, S, K, W]`` — n_bits/8 bytes per
-element on the KV HBM stream — unpacks it with the gather-free superblock
-lanes of :func:`repro.kernels.bits.unpack_bits`, decodes branch-free
+streams one kv tile of packed uint32 words of K and V per batch row — every
+kv head side by side, ``[tile, K*W]`` with ``W = packed_words(head_dim)`` —
+n_bits/8 bytes per element on the KV HBM stream, decodes it branch-free
 in-register (:func:`repro.kernels.f2p_quant.dequantize_tile_math`), applies
 the per-(position, head) scale, and folds the tile into an online-softmax
 running (acc, m, l) state. Byte-aligned codes or f32 KV are never
 materialized in HBM.
 
+Storage-order decode (``decode_order(fmt) == "planes"``): when ``n_bits``
+divides 32, no field straddles a word, so with ``P = 32 // n_bits`` the
+fields of a word tile split into P planes by a shift and a mask,
+``(words >> p*n_bits) & mask``, and plane ``p`` holds element ``P*w + p``
+of head ``h`` in lane ``h*W + w`` — the lane its word already occupies.
+Nothing moves lanes to decode: the field permutation moves onto q instead
+(``q_planes[p, r, h*W + w] = q[r, h, P*w + p]``, a small XLA transpose
+before the kernel) and is undone on the output after it. Scores are the
+elementwise plane products summed per head by a 0/1 ``[K*W, K*R]``
+selection matmul; the probabilities and the per-(token, head) scales reach
+their heads' lanes through the transposed 0/1 maps. Every selection matmul
+runs at ``Precision.HIGHEST``, so no f32 value is rounded to bf16, and
+every decoded K/V value is bitwise the value :func:`_decode_rows` gives.
+Fields of other widths (6-bit) straddle words, the plane identity fails,
+and those formats keep the per-head decode: ``unpack_bits_mxu`` routes one
+head's bytes to its field lanes through byte-plane matmuls, head by head
+(``"per_head"``).
+
 GQA head folding: q ``[B, Sq, H, hd]`` with H = K*G is reshaped to
 ``[B, K, R, hd]`` rows R = G*Sq (row r = g*Sq + s), so each kv head's
-decoded tile feeds all G query heads (and all Sq query positions) at once;
-one kernel step streams the tile of every kv head of one batch row. Causal
-masks recover the query position as ``q_offset + r % Sq``.
+decoded tile feeds all G query heads (and all Sq query positions) at once.
+Causal masks recover the query position as ``q_offset + r % Sq``.
 
-Backends (dispatch op ``attention_packed``):
+Backends (dispatch ops ``attention_packed`` / ``attention_paged``):
 
   ``pallas`` / ``pallas_interpret``  the Pallas kernel, grid (B, S/tile)
                                      with the kv-tile axis innermost —
@@ -28,11 +44,11 @@ Backends (dispatch op ``attention_packed``):
                                      revisited output blocks exactly like
                                      the matmul K-axis accumulator
   ``xla``                            the SAME per-tile math (shared helpers
-                                     below) as a ``lax.scan`` over kv tiles,
-                                     with unpack + decode + attention fused
-                                     under one jit — the semantics oracle
+                                     below) as a tile loop over the decoded
+                                     cache under one jit — the semantics
+                                     oracle
 
-All three run the identical op sequence in f32, so fused outputs are
+All run the identical op sequence in f32, so fused outputs are
 bitwise-identical to the unpack-then-dequant-then-attend reference
 (:func:`attention_packed_reference`) — pinned by ``tests/test_attention.py``
 across formats × n_bits × odd sequence lengths.
@@ -44,6 +60,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -56,7 +73,8 @@ from repro.kernels.f2p_quant import dequantize_tile_math
 __all__ = ["attention_packed", "attention_packed_reference",
            "attention_paged", "attention_paged_reference",
            "gather_pages_to_dense", "attention_reference", "attention_tile",
-           "set_attention_tile", "autotune_attention_tile", "DEFAULT_TILE"]
+           "set_attention_tile", "autotune_attention_tile", "decode_order",
+           "DEFAULT_TILE"]
 
 # kv-tile length (cache positions per grid step). Per-(backend, n_bits)
 # overrides mirror the matmul tile table (f2p_matmul._TILE_TABLE): narrow
@@ -75,9 +93,25 @@ def set_attention_tile(backend: str, n_bits: int, tile: int) -> None:
     _TILE_TABLE[(backend, int(n_bits))] = int(tile)
 
 
+def decode_order(fmt: F2PFormat) -> str:
+    """``"planes"`` where the kernels decode packed KV in storage order
+    (``32 % n_bits == 0``: no field straddles a word), else ``"per_head"``
+    (the per-head MXU unpack)."""
+    return "planes" if 32 % fmt.n_bits == 0 else "per_head"
+
+
+def _plane_counts(fmt_k: F2PFormat, fmt_v: F2PFormat, hd: int):
+    """(P_k, P_v) fields per word when both caches decode in storage order
+    and every head fills whole words, else None (the per-head path)."""
+    if any(decode_order(f) != "planes" or hd % (32 // f.n_bits)
+           for f in (fmt_k, fmt_v)):
+        return None
+    return 32 // fmt_k.n_bits, 32 // fmt_v.n_bits
+
+
 # ---------------------------------------------------------------------------
 # Shared per-tile math — ONE implementation used by the Pallas kernel body
-# AND the xla scan, so the backends agree bitwise.
+# AND the xla tile loop, so the backends agree bitwise.
 # ---------------------------------------------------------------------------
 def _decode_rows(words, scales, fmt: F2PFormat, hd: int,
                  unpack=unpack_bits):
@@ -88,13 +122,16 @@ def _decode_rows(words, scales, fmt: F2PFormat, hd: int,
     return dequantize_tile_math(codes, fmt, jnp.float32) * scales
 
 
-def _tile_mask(j, tile: int, rows: int, sq: int, causal: bool, kvlen, qoff):
-    """[rows, tile] validity of kv tile ``j``: position < kvlen, and (causal)
-    position <= the row's query position q_offset + r % Sq."""
-    kpos = j * tile + jax.lax.broadcasted_iota(jnp.int32, (rows, tile), 1)
+def _tile_mask(j, tile: int, rows: int, sq: int, causal: bool, kvlen, qoff,
+               tokens_axis: int = 1):
+    """Validity of kv tile ``j`` ([rows, tile], or [tile, rows] with
+    ``tokens_axis=0``): position < kvlen, and (causal) position <= the
+    row's query position q_offset + r % Sq."""
+    shape = (rows, tile) if tokens_axis == 1 else (tile, rows)
+    kpos = j * tile + jax.lax.broadcasted_iota(jnp.int32, shape, tokens_axis)
     valid = kpos < kvlen
     if causal:
-        r = jax.lax.broadcasted_iota(jnp.int32, (rows, tile), 0)
+        r = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - tokens_axis)
         valid = valid & (kpos <= qoff + r % sq)
     return valid
 
@@ -134,28 +171,142 @@ def _unfold_o(o3, sq: int, dtype):
     return o.reshape(B, sq, K * G, hd).astype(dtype)
 
 
+# -- storage-order (plane) decode --------------------------------------------
+def _select(x, sel):
+    """``x @ sel`` for a 0/1 ``sel``: moves f32 lanes (one 1 per column) or
+    sums lane groups on the MXU, at HIGHEST so no f32 value is rounded to
+    bf16 (an ambient matmul precision cannot lower it)."""
+    return jnp.dot(x, sel, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
+
+
+def _selectors(K: int, R: int, Wk: int, Wv: int):
+    """The plane layout's static 0/1 lane maps (numpy): ``sel_k [R, K*Wk,
+    K*R]`` sends lane h*Wk + w to score column h*R + r; ``sel_v [R, K*R,
+    K*Wv]`` sends column h*R + r to every lane of head h; ``exp_k [K,
+    K*Wk]`` / ``exp_v [K, K*Wv]`` send head h's scale to its lanes."""
+    col = np.arange(K * R)
+
+    def sel(W):
+        head = np.arange(K * W)[:, None] // W
+        return np.stack([(head == col // R) & (col % R == r)
+                         for r in range(R)]).astype(np.float32)
+
+    def exp(W):
+        return (np.arange(K)[:, None] == np.arange(K * W) // W).astype(
+            np.float32)
+
+    return sel(Wk), sel(Wv).transpose(0, 2, 1), exp(Wk), exp(Wv)
+
+
+def _to_planes(x, P: int):
+    """[B, N, K, hd] -> [B, P, N, K*W] storage order: plane p, lane
+    h*W + w holds element P*w + p of head h (W = hd // P), the lane of the
+    word that stores it (DESIGN §9's little-endian fields)."""
+    B, N, K, hd = x.shape
+    x = x.reshape(B, N, K, hd // P, P).transpose(0, 4, 1, 2, 3)
+    return x.reshape(B, P, N, K * (hd // P))
+
+
+def _from_planes(x, K: int):
+    """Inverse of :func:`_to_planes`: [B, P, N, K*W] -> [B, N, K, P*W]."""
+    B, P, N, L = x.shape
+    x = x.reshape(B, P, N, K, L // K).transpose(0, 2, 3, 4, 1)
+    return x.reshape(B, N, K, P * (L // K))
+
+
+def _q_planes(q3, P: int):
+    """[B, K, R, hd] folded q -> [B, P, R, K*W] in K's storage order."""
+    return _to_planes(q3.transpose(0, 2, 1, 3), P)
+
+
+def _o_unplanes(acc, K: int):
+    """[B, P, R, K*W] output in V's storage order -> [B, K, R, hd]."""
+    return _from_planes(acc, K).transpose(0, 2, 1, 3)
+
+
+def _decode_planes(words, scales, fmt: F2PFormat, expand):
+    """[T, K*W] uint32 words + [T, K] f32 scales -> P planes [T, K*W] f32
+    in storage order (plane p = field p of every word), each value bitwise
+    the value :func:`_decode_rows` gives it. ``expand`` is the [K, K*W] 0/1
+    map of each head's scale to its lanes (exact)."""
+    n = fmt.n_bits
+    sc = _select(scales, expand)
+    mask = jnp.uint32((1 << n) - 1)
+    return [dequantize_tile_math(((words >> jnp.uint32(p * n)) & mask)
+                                 .astype(jnp.int32), fmt, jnp.float32) * sc
+            for p in range(32 // n)]
+
+
+def _tile_sum(x):
+    """Sum over axis 0 as a tree of halves: one association on every
+    backend. (XLA's CPU reductions pick their order from the operand's
+    layout, which differs between the kernel body and the xla loop.)"""
+    while x.shape[0] > 1:
+        h = x.shape[0] // 2
+        y = x[:h] + x[h:2 * h]
+        x = jnp.concatenate([y, x[2 * h:]]) if x.shape[0] % 2 else y
+    return x
+
+
+def _plane_step(q, k, v, valid, acc, m, l, scale, sel_k, sel_v):
+    """One online-softmax update of every kv head at once, in storage order.
+
+    q ``[Pk][R]`` rows [1, K*Wk] (q in K's planes); k ``[Pk]`` / v ``[Pv]``
+    decoded planes [T, K*W]; valid [T, K*R]; running acc ``[Pv][R]`` rows
+    [1, K*Wv], m / l [1, K*R] (column h*R + r); sel_k / sel_v the
+    ``[R]`` 0/1 maps of :func:`_selectors`. The math is :func:`_online_step`
+    per (head, row), with the sums over head_dim and tile reassociated."""
+    s = None
+    for r, sel in enumerate(sel_k):
+        prod = k[0] * q[0][r]
+        for kp, qp in zip(k[1:], q[1:]):
+            prod = prod + kp * qp[r]
+        sr = _select(prod, sel)            # head sums, row r's columns only
+        s = sr if s is None else s + sr
+    s = jnp.where(valid, s * scale, -jnp.inf)
+    m_new = jnp.maximum(m, s.max(axis=0, keepdims=True))
+    safe_m = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+    p = jnp.exp(s - safe_m)
+    corr = jnp.exp(jnp.where(jnp.isfinite(m), m - safe_m, -jnp.inf))
+    l_new = l * corr + _tile_sum(p)
+    acc_new = [list(a) for a in acc]
+    for r, sel in enumerate(sel_v):
+        pe, ce = _select(p, sel), _select(corr, sel)
+        for i, vp in enumerate(v):
+            acc_new[i][r] = acc[i][r] * ce + _tile_sum(pe * vp)
+    return acc_new, m_new, l_new
+
+
+def _plane_finalize(acc, l, sel_v):
+    """[Pv][R] rows of acc / their (head, row)'s l, spread to its lanes."""
+    return [[_finalize(a, _select(l, sel)) for a, sel in zip(rows, sel_v)]
+            for rows in acc]
+
+
 # ---------------------------------------------------------------------------
-# xla backend: unpack + decode + online-softmax attention under ONE jit —
-# the semantics oracle the Pallas kernel is pinned against.
+# xla backend: decode + online-softmax attention under ONE jit — the
+# semantics oracle the Pallas kernel is pinned against. The staged reference
+# runs the same tile loop on a cache dequantized by a separate jit.
 # ---------------------------------------------------------------------------
-@functools.partial(jax.jit, static_argnames=("fmt_k", "fmt_v", "sq",
-                                             "causal", "tile"))
-def _attention_xla(q3, kw, ks, vw, vs, lens, *, fmt_k, fmt_v, sq, causal,
-                   tile):
+def _attend_decoded(q3, k, v, lens, *, sq, causal, tile, planes):
+    """Tile loop over decoded ``[B, S, K, hd]`` f32 k/v: per head
+    (``planes`` None) or in storage order (``planes`` = (P_k, P_v))."""
     B, K, R, hd = q3.shape
-    S = kw.shape[1]
-    k = _decode_rows(kw, ks, fmt_k, hd)          # [B, S, K, hd] f32
-    v = _decode_rows(vw, vs, fmt_v, hd)
+    S = k.shape[1]
     nt = -(-S // tile)
     pad = nt * tile - S
     if pad:
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    kvlen, qoff = lens[:, 0], lens[:, 1]          # per-batch [B]
+    scale = 1.0 / math.sqrt(hd)
+    if planes is not None:
+        return _planes_loop(q3, k, v, kvlen, qoff, planes=planes, sq=sq,
+                            causal=causal, tile=tile, scale=scale)
     # [nt, B, K, tile, hd]: per-(batch, head) tiles in kernel layout
     kt = k.reshape(B, nt, tile, K, hd).transpose(1, 0, 3, 2, 4)
     vt = v.reshape(B, nt, tile, K, hd).transpose(1, 0, 3, 2, 4)
-    kvlen, qoff = lens[:, 0], lens[:, 1]          # per-batch [B]
-    scale = 1.0 / math.sqrt(hd)
     step = jax.vmap(jax.vmap(_online_step, in_axes=(0, 0, 0, None, 0, 0, 0,
                                                     None)),
                     in_axes=(0, 0, 0, 0, 0, 0, 0, None))
@@ -176,6 +327,61 @@ def _attention_xla(q3, kw, ks, vw, vs, lens, *, fmt_k, fmt_v, sq, causal,
     return _finalize(acc, l)
 
 
+def _planes_loop(q3, k, v, kvlen, qoff, *, planes, sq, causal, tile, scale):
+    """Storage-order twin of the plane kernel: one batch row at a time
+    (``lax.map``) and one tile at a time (``lax.scan``), so every
+    :func:`_plane_step` call sees the kernel body's shapes."""
+    B, K, R, hd = q3.shape
+    Pk, Pv = planes
+    nt = k.shape[1] // tile
+    sel_k, sel_v, _, _ = (jnp.asarray(x) for x in
+                          _selectors(K, R, hd // Pk, hd // Pv))
+    sel_k, sel_v = list(sel_k), list(sel_v)
+
+    def tiles(x, P):                    # [B, S, K, hd] -> [B, nt, P, tile, L]
+        x = _to_planes(x, P)
+        return x.reshape(B, P, nt, tile, -1).transpose(0, 2, 1, 3, 4)
+
+    def row(args):
+        qp, kt, vt, kl, qo = args
+        q = [[qp[p, r:r + 1] for r in range(R)] for p in range(Pk)]
+
+        def body(carry, inp):
+            acc, m, l = carry
+            j, kb, vb = inp
+            valid = _tile_mask(j, tile, K * R, sq, causal, kl, qo,
+                               tokens_axis=0)
+            return _plane_step(q, list(kb), list(vb), valid, acc, m, l,
+                               scale, sel_k, sel_v), None
+
+        acc0 = [[jnp.zeros((1, K * hd // Pv), jnp.float32)] * R] * Pv
+        m0 = jnp.full((1, K * R), -jnp.inf, jnp.float32)
+        l0 = jnp.zeros((1, K * R), jnp.float32)
+        (acc, _, l), _ = jax.lax.scan(body, (acc0, m0, l0),
+                                      (jnp.arange(nt), kt, vt))
+        return jnp.stack([jnp.concatenate(rows, axis=0)
+                          for rows in _plane_finalize(acc, l, sel_v)])
+
+    acc = jax.lax.map(row, (_q_planes(q3, Pk), tiles(k, Pk), tiles(v, Pv),
+                            kvlen, qoff))
+    return _o_unplanes(acc, K)
+
+
+@functools.partial(jax.jit, static_argnames=("fmt_k", "fmt_v", "sq",
+                                             "causal", "tile"))
+def _attention_xla(q3, kw, ks, vw, vs, lens, *, fmt_k, fmt_v, sq, causal,
+                   tile):
+    hd = q3.shape[-1]
+    return _attend_decoded(q3, _decode_rows(kw, ks, fmt_k, hd),
+                           _decode_rows(vw, vs, fmt_v, hd), lens, sq=sq,
+                           causal=causal, tile=tile,
+                           planes=_plane_counts(fmt_k, fmt_v, hd))
+
+
+_reference_jit = jax.jit(_attend_decoded,
+                         static_argnames=("sq", "causal", "tile", "planes"))
+
+
 # ---------------------------------------------------------------------------
 # Pallas kernels: grid (B, S/tile), kv-tile axis innermost/sequential; the
 # online-softmax state of every kv head lives in the revisited per-batch
@@ -184,23 +390,61 @@ def _attention_xla(q3, kw, ks, vw, vs, lens, *, fmt_k, fmt_v, sq, causal,
 # as lane-dense rows — words [tile, K*W] and scales [tile, K], every kv
 # head side by side — because Mosaic wants the last two block dims to be
 # whole array dims or (8, 128) multiples, and [.., K, W] blocks are 1 head
-# tall. Head h's words start at lane h*W, which the MXU unpack absorbs; its
-# scale column is a masked lane sum (one value plus zeros: exact). Lengths
-# ride in SMEM as scalar-prefetch operands.
+# tall. Lengths ride in SMEM as scalar-prefetch operands. The dense and the
+# paged kernel differ only in how the word tile arrives (one block, or
+# one block per page), so both run one body per decode order.
 # ---------------------------------------------------------------------------
-def _attend_heads(fmt_k, fmt_v, sq, causal, scale, tile, nt, kw, ks, vw, vs,
-                  q_ref, o_ref, m_ref, l_ref, kvlen, qoff):
-    """Fold kv tile ``program_id(1)`` (rows ``kw``/``vw`` [tile, K*W],
-    ``ks``/``vs`` [tile, K]) into every head's running (acc, m, l). The
-    per-head math is the xla scan's, op for op."""
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
+def _init_state(o_ref, m_ref, l_ref):
+    @pl.when(pl.program_id(1) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
 
+
+def _attend_planes(fmt_k, fmt_v, sq, causal, scale, tile, nt, kw, ks, vw, vs,
+                   q_ref, selk_ref, selv_ref, expk_ref, expv_ref,
+                   o_ref, m_ref, l_ref, kvlen, qoff):
+    """Fold kv tile ``program_id(1)`` into every head's running (acc, m, l)
+    in storage order: ``q_ref`` [Pk, R, K*Wk] (q in K's planes), ``o_ref``
+    [Pv, R, K*Wv], ``m_ref`` / ``l_ref`` [1, K*R]. The math is the xla
+    loop's :func:`_plane_step`, op for op."""
+    j = pl.program_id(1)
+    _init_state(o_ref, m_ref, l_ref)
+    Pk, R = q_ref.shape[0], q_ref.shape[1]
+    Pv = o_ref.shape[0]
+    sel_k = [selk_ref[r] for r in range(R)]
+    sel_v = [selv_ref[r] for r in range(R)]
+    k = _decode_planes(kw, ks, fmt_k, expk_ref[...])
+    v = _decode_planes(vw, vs, fmt_v, expv_ref[...])
+    valid = _tile_mask(j, tile, m_ref.shape[-1], sq, causal, kvlen, qoff,
+                       tokens_axis=0)
+    q = [[q_ref[p, r:r + 1, :] for r in range(R)] for p in range(Pk)]
+    acc = [[o_ref[i, r:r + 1, :] for r in range(R)] for i in range(Pv)]
+    acc, m, l = _plane_step(q, k, v, valid, acc, m_ref[...], l_ref[...],
+                            scale, sel_k, sel_v)
+    for i in range(Pv):
+        for r in range(R):
+            o_ref[i, r:r + 1, :] = acc[i][r]
+    m_ref[...] = m
+    l_ref[...] = l
+
+    @pl.when(j == nt - 1)
+    def _fin():
+        for i, rows in enumerate(_plane_finalize(acc, l, sel_v)):
+            for r, o in enumerate(rows):
+                o_ref[i, r:r + 1, :] = o
+
+
+def _attend_heads(fmt_k, fmt_v, sq, causal, scale, tile, nt, kw, ks, vw, vs,
+                  q_ref, o_ref, m_ref, l_ref, kvlen, qoff):
+    """Per-head twin for formats whose fields straddle words: ``q_ref`` /
+    ``o_ref`` [K, R, hd], ``m_ref`` / ``l_ref`` [K, R, 1]. Head h's words
+    start at lane h*W, which the MXU unpack absorbs; its scale column is a
+    masked lane sum (one value plus zeros: exact). The per-head math is the
+    xla scan's, op for op."""
+    j = pl.program_id(1)
+    _init_state(o_ref, m_ref, l_ref)
     K, R, hd = q_ref.shape
     Wk, Wv = kw.shape[-1] // K, vw.shape[-1] // K
     lane = jax.lax.broadcasted_iota(jnp.int32, ks.shape, 1)
@@ -227,21 +471,64 @@ def _attend_heads(fmt_k, fmt_v, sq, causal, scale, tile, nt, kw, ks, vw, vs,
             o_ref[h] = _finalize(o_ref[h], l_ref[h])
 
 
-def _fused_kernel(fmt_k, fmt_v, sq, causal, scale, tile, nt,
-                  len_ref, q_ref, kw_ref, ks_ref, vw_ref, vs_ref,
-                  o_ref, m_ref, l_ref):
+def _kernel(attend, n_prefetch, ppt, *refs):
+    """Pallas body shared by the dense and the paged call: after the
+    scalar-prefetch refs (the paged call's page table, then lengths) come
+    q, ``ppt`` word blocks each of K and V (pages concatenate back into the
+    contiguous tile), the two scale blocks, then ``attend``'s constant maps
+    and state outputs."""
+    len_ref = refs[n_prefetch - 1]
+    q_ref, rest = refs[n_prefetch], refs[n_prefetch + 1:]
+    kw, vw = (jnp.concatenate([r[...] for r in rest[i * ppt:(i + 1) * ppt]],
+                              axis=0) if ppt > 1 else rest[i * ppt][...]
+              for i in range(2))
+    ks_ref, vs_ref = rest[2 * ppt:2 * ppt + 2]
     b = pl.program_id(0)
-    _attend_heads(fmt_k, fmt_v, sq, causal, scale, tile, nt, kw_ref[...],
-                  ks_ref[...], vw_ref[...], vs_ref[...], q_ref, o_ref,
-                  m_ref, l_ref, len_ref[b, 0], len_ref[b, 1])
+    attend(kw, ks_ref[...], vw, vs_ref[...], q_ref, *rest[2 * ppt + 2:],
+           len_ref[b, 0], len_ref[b, 1])
 
 
-def _kernel_out(B: int, K: int, R: int, hd: int, index_map):
-    """Out specs + shapes of the per-batch (acc, m, l) state blocks."""
-    specs = [pl.BlockSpec((None, K, R, d), index_map) for d in (hd, 1, 1)]
-    shapes = [jax.ShapeDtypeStruct((B, K, R, d), jnp.float32)
-              for d in (hd, 1, 1)]
-    return specs, shapes
+def _pallas_attend(q3, kv_specs, kv_args, prefetch, nt, ppt, *, fmt_k, fmt_v,
+                   sq, causal, tile, interpret):
+    """The pallas_call of both kernels: ``kv_specs``/``kv_args`` are the K
+    and V word blocks and the [B, S, K] scale rows; ``prefetch`` the scalar
+    operands (lengths last). Picks the body by decode order."""
+    B, K, R, hd = q3.shape
+    scale = 1.0 / math.sqrt(hd)   # static: python float, f32 at use sites
+    statics = (fmt_k, fmt_v, sq, causal, scale, tile, nt)
+
+    def whole(x):                       # the same block at every grid step
+        return pl.BlockSpec(x.shape, lambda *a, _n=x.ndim: (0,) * _n)
+
+    def per_row(shape):                 # batch row b's block
+        return pl.BlockSpec((None,) + shape,
+                            lambda b, *a, _n=len(shape): (b,) + (0,) * _n)
+
+    planes = _plane_counts(fmt_k, fmt_v, hd)
+    if planes is None:
+        attend = functools.partial(_attend_heads, *statics)
+        q, consts = q3, []
+        state = [(K, R, hd), (K, R, 1), (K, R, 1)]
+    else:
+        (Pk, Pv), (Lk, Lv) = planes, (kv_args[0].shape[-1],
+                                      kv_args[ppt].shape[-1])
+        attend = functools.partial(_attend_planes, *statics)
+        q = _q_planes(q3, Pk)
+        consts = [jnp.asarray(x) for x in
+                  _selectors(K, R, Lk // K, Lv // K)]
+        state = [(Pv, R, Lv), (1, K * R), (1, K * R)]
+    out = pl.pallas_call(
+        functools.partial(_kernel, attend, len(prefetch), ppt),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch), grid=(B, nt),
+            in_specs=[per_row(q.shape[1:])] + kv_specs
+            + [whole(x) for x in consts],
+            out_specs=[per_row(s) for s in state]),
+        out_shape=[jax.ShapeDtypeStruct((B,) + s, jnp.float32)
+                   for s in state],
+        interpret=interpret,
+    )(*prefetch, q, *kv_args, *consts)[0]
+    return out if planes is None else _o_unplanes(out, K)
 
 
 def _heads_in_lanes(x):
@@ -253,7 +540,6 @@ def _heads_in_lanes(x):
                                              "tile", "interpret"))
 def _attention_pallas(q3, kw, ks, vw, vs, lens, *, fmt_k, fmt_v, sq, causal,
                       tile, interpret):
-    B, K, R, hd = q3.shape
     S = kw.shape[1]
     nt = -(-S // tile)
     pad = nt * tile - S
@@ -264,25 +550,12 @@ def _attention_pallas(q3, kw, ks, vw, vs, lens, *, fmt_k, fmt_v, sq, causal,
         ks = jnp.pad(ks, ((0, 0), (0, pad), (0, 0), (0, 0)))
         vw = jnp.pad(vw, ((0, 0), (0, pad), (0, 0), (0, 0)))
         vs = jnp.pad(vs, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    scale = 1.0 / math.sqrt(hd)   # static: python float, f32 at use sites
-    kv = [_heads_in_lanes(x) for x in (kw, ks, vw, vs)]
-    out_specs, out_shape = _kernel_out(B, K, R, hd,
-                                       lambda b, j, lens: (b, 0, 0, 0))
-    out, _, _ = pl.pallas_call(
-        functools.partial(_fused_kernel, fmt_k, fmt_v, sq, causal, scale,
-                          tile, nt),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(B, nt),
-            in_specs=[pl.BlockSpec((None, K, R, hd),
-                                   lambda b, j, lens: (b, 0, 0, 0))]
-            + [pl.BlockSpec((None, tile, x.shape[-1]),
-                            lambda b, j, lens: (b, j, 0)) for x in kv],
-            out_specs=out_specs),
-        out_shape=out_shape,
-        interpret=interpret,
-    )(lens, q3, *kv)
-    return out
+    kv = [_heads_in_lanes(x) for x in (kw, vw, ks, vs)]
+    specs = [pl.BlockSpec((None, tile, x.shape[-1]),
+                          lambda b, j, lens: (b, j, 0)) for x in kv]
+    return _pallas_attend(q3, specs, kv, (lens,), nt, 1, fmt_k=fmt_k,
+                          fmt_v=fmt_v, sq=sq, causal=causal, tile=tile,
+                          interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -389,33 +662,18 @@ def _attention_paged_xla(q3, kw, ks, vw, vs, pages, lens, *, fmt_k, fmt_v,
                           causal=causal, tile=tile)
 
 
-def _paged_kernel(fmt_k, fmt_v, sq, causal, scale, tile, nt, ppt,
-                  ids_ref, len_ref, q_ref, *refs):
-    """Pallas body: the grid's kv step j receives its word tile as ``ppt``
-    separate page blocks, DMA'd straight from the pool slabs through the
-    scalar-prefetched page table (the index_maps below read ``ids_ref``).
-    Concatenating the page blocks re-forms the contiguous tile, after which
-    the math is byte-for-byte the dense kernel's."""
-    kw, vw = (
-        jnp.concatenate([r[...] for r in refs[i * ppt:(i + 1) * ppt]],
-                        axis=0)
-        for i in range(2))
-    ks_ref, vs_ref, o_ref, m_ref, l_ref = refs[2 * ppt:]
-    ks, vs = ks_ref[...], vs_ref[...]
-    b = pl.program_id(0)
-    _attend_heads(fmt_k, fmt_v, sq, causal, scale, tile, nt, kw, ks, vw, vs,
-                  q_ref, o_ref, m_ref, l_ref, len_ref[b, 0], len_ref[b, 1])
-
-
 @functools.partial(jax.jit, static_argnames=("fmt_k", "fmt_v", "sq", "causal",
                                              "tile", "interpret"))
 def _attention_paged_pallas(q3, kw, ks, vw, vs, pages, lens, *, fmt_k, fmt_v,
                             sq, causal, tile, interpret):
-    B, K, R, hd = q3.shape
+    """The kernel body's word tile arrives as ``ppt`` separate page blocks,
+    DMA'd straight from the pool slabs through the scalar-prefetched page
+    table; concatenating them re-forms the contiguous tile, after which the
+    math is byte-for-byte the dense kernel's."""
+    B, K = q3.shape[:2]
     T = kw.shape[1]
     ppt = tile // T
     nt = pages.shape[1] // ppt
-    scale = 1.0 / math.sqrt(hd)
 
     def page_spec(x, p):
         # one page block per spec: page p of kv tile j lives at slab page
@@ -432,24 +690,13 @@ def _attention_paged_pallas(q3, kw, ks, vw, vs, pages, lens, *, fmt_k, fmt_v,
     def dense(slab):
         return jnp.take(slab, pages, axis=0).reshape(B, nt * tile, K)
 
-    in_specs = [pl.BlockSpec((None, K, R, hd),
-                             lambda b, j, ids, lens: (b, 0, 0, 0))]
-    for x in (kw, vw):
-        in_specs.extend(page_spec(x, p) for p in range(ppt))
-    in_specs += [pl.BlockSpec((None, tile, K),
-                              lambda b, j, ids, lens: (b, j, 0))] * 2
-    out_specs, out_shape = _kernel_out(
-        B, K, R, hd, lambda b, j, ids, lens: (b, 0, 0, 0))
-    out, _, _ = pl.pallas_call(
-        functools.partial(_paged_kernel, fmt_k, fmt_v, sq, causal, scale,
-                          tile, nt, ppt),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(B, nt), in_specs=in_specs,
-            out_specs=out_specs),
-        out_shape=out_shape,
-        interpret=interpret,
-    )(pages, lens, q3, *([kw] * ppt), *([vw] * ppt), dense(ks), dense(vs))
-    return out
+    specs = [page_spec(x, p) for x in (kw, vw) for p in range(ppt)]
+    specs += [pl.BlockSpec((None, tile, K),
+                           lambda b, j, ids, lens: (b, j, 0))] * 2
+    args = [kw] * ppt + [vw] * ppt + [dense(ks), dense(vs)]
+    return _pallas_attend(q3, specs, args, (pages, lens), nt, ppt,
+                          fmt_k=fmt_k, fmt_v=fmt_v, sq=sq, causal=causal,
+                          tile=tile, interpret=interpret)
 
 
 @dispatch.register("attention_paged", dispatch.PALLAS)
@@ -571,52 +818,20 @@ def attention_paged_reference(q, kq: QTensor, vq: QTensor, pages, *,
 
 
 def attention_reference(q, k, v, *, kv_len=None, causal: bool = False,
-                        q_offset=0, tile: int = DEFAULT_TILE):
+                        q_offset=0, tile: int = DEFAULT_TILE, planes=None):
     """Dense-KV online-softmax reference: the SAME tile loop as the fused
     backends, on already-dequantized ``[B, S, K, hd]`` k/v. Matches
     ``naive_attention`` numerically and the fused paths bitwise (given the
-    same tile)."""
+    same tile and, for formats decoded in storage order, their ``planes``
+    = (P_k, P_v) fields per word)."""
     B, Sq, H, hd = q.shape
     S, K = k.shape[1], k.shape[2]
     tile = max(1, min(int(tile), S))
     lens = _make_lens(kv_len, q_offset, B, S)
     o3 = _reference_jit(_fold_q(q, K), k.astype(jnp.float32),
                         v.astype(jnp.float32), lens, sq=Sq,
-                        causal=bool(causal), tile=tile)
+                        causal=bool(causal), tile=tile, planes=planes)
     return _unfold_o(o3, Sq, q.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("sq", "causal", "tile"))
-def _reference_jit(q3, k, v, lens, *, sq, causal, tile):
-    B, K, R, hd = q3.shape
-    S = k.shape[1]
-    nt = -(-S // tile)
-    pad = nt * tile - S
-    if pad:
-        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    kt = k.reshape(B, nt, tile, K, hd).transpose(1, 0, 3, 2, 4)
-    vt = v.reshape(B, nt, tile, K, hd).transpose(1, 0, 3, 2, 4)
-    kvlen, qoff = lens[:, 0], lens[:, 1]          # per-batch [B]
-    scale = 1.0 / math.sqrt(hd)
-    step = jax.vmap(jax.vmap(_online_step, in_axes=(0, 0, 0, None, 0, 0, 0,
-                                                    None)),
-                    in_axes=(0, 0, 0, 0, 0, 0, 0, None))
-
-    def body(carry, inp):
-        acc, m, l = carry
-        j, (kb, vb) = inp
-        valid = jax.vmap(
-            lambda kl, qo: _tile_mask(j, tile, R, sq, causal, kl, qo)
-        )(kvlen, qoff)                            # [B, R, tile]
-        return step(q3, kb, vb, valid, acc, m, l, scale), None
-
-    acc0 = jnp.zeros((B, K, R, hd), jnp.float32)
-    m0 = jnp.full((B, K, R, 1), -jnp.inf, jnp.float32)
-    l0 = jnp.zeros((B, K, R, 1), jnp.float32)
-    (acc, m, l), _ = jax.lax.scan(body, (acc0, m0, l0),
-                                  (jnp.arange(nt), (kt, vt)))
-    return _finalize(acc, l)
 
 
 def attention_packed_reference(q, kq: QTensor, vq: QTensor, *, kv_len=None,
@@ -629,8 +844,9 @@ def attention_packed_reference(q, kq: QTensor, vq: QTensor, *, kv_len=None,
     ``benchmarks.run --only attention``."""
     k = kq.dequantize(jnp.float32)
     v = vq.dequantize(jnp.float32)
-    return attention_reference(q, k, v, kv_len=kv_len, causal=causal,
-                               q_offset=q_offset, tile=tile)
+    return attention_reference(
+        q, k, v, kv_len=kv_len, causal=causal, q_offset=q_offset, tile=tile,
+        planes=_plane_counts(kq.fmt, vq.fmt, q.shape[-1]))
 
 
 def autotune_attention_tile(backend: str, n_bits: int, *,

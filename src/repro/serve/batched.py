@@ -77,6 +77,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
+from repro.kernels.f2p_attention import decode_order
 from repro.models import init_caches
 from repro.models.config import ModelConfig
 from repro.serve.arch import SupportedArchitecture, arch_for
@@ -228,6 +229,11 @@ class BatchedEngine:
                     n_pages = B * maxp + maxp   # all slots + one transit
             self.pool = PagedKVPool(cfg, T, n_pages,
                                     kv_policy=bscfg.kv_policy)
+            # how the attention kernels decode this pool's formats
+            # (storage-order planes or the per-head unpack), for stats
+            orders = {decode_order(self.pool.slabs[key][kv].fmt)
+                      for key in self.pool.attn_keys for kv in ("k", "v")}
+            self.attn_decode_order = "/".join(sorted(orders)) or None
             if self.paged:
                 # page 0, allocated for the engine's lifetime: retired slot
                 # rows point here and their clamped dead-position writes land
@@ -360,6 +366,7 @@ class BatchedEngine:
         if self.pool is not None:
             d["pool"] = self.pool.stats()
             d["reserved_pages"] = 1 if self.paged else 0
+            d["attn_decode_order"] = self.attn_decode_order
         return d
 
     # -- slab <-> cache binding (paged decode) ------------------------------

@@ -175,3 +175,79 @@ def test_model_decode_step_paged_logits_bitwise(backend, monkeypatch):
         np.testing.assert_array_equal(np.asarray(ld), np.asarray(lp))
         tok = jnp.argmax(ld, -1).astype(jnp.int32)[:, None]
         pos = pos + 1
+
+
+# ---------------------------------------------------------------------------
+# Storage-order ("planes") decode: formats whose fields never straddle a
+# word decode where they lie, with the field permutation moved onto q and
+# the output (kernels/f2p_attention.py module docstring, DESIGN.md §11.2).
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("backend", ["xla", "pallas_interpret"])
+@pytest.mark.parametrize("K,G,hd", [(32, 1, 96), (8, 4, 128)],
+                         ids=["mha_k32_hd96", "gqa_k8_g4_hd128"])
+def test_paged_bitwise_vs_gather_to_dense_at_serving_widths(K, G, hd,
+                                                            backend):
+    """The benchmark cell's MHA widths (32 kv heads of 96: 768 lanes of
+    words) and a GQA group of 4 at head_dim 128, 8-token pages, tile 128:
+    paged == dense gather + attention_packed, bit for bit, over two tiles
+    with rows ending mid-tile, mid-page and on a full span."""
+    fmt = FORMATS[1]
+    q, kq, vq, pages = _case(7, B=3, P=70, maxp=32, K=K, G=G, hd=hd,
+                             fmt=fmt)
+    kv_len = jnp.asarray([131, 256, 5], jnp.int32)
+    ref = FA.attention_paged_reference(q, kq, vq, pages, kv_len=kv_len,
+                                       tile=128)
+    got = FA.attention_paged(q, kq, vq, pages, kv_len=kv_len,
+                             backend=backend, tile=128)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+
+
+@pytest.mark.parametrize("n_bits,order", [(4, "planes"), (8, "planes"),
+                                          (16, "planes"), (6, "per_head")])
+def test_decode_order_follows_word_straddling(n_bits, order):
+    """Storage order needs every field inside one word: 32 % n_bits == 0.
+    6-bit fields straddle words and keep the per-head unpack."""
+    h_bits = 1 if n_bits < 6 else 2      # 4 bits leave room for H=1 only
+    assert FA.decode_order(F2PFormat(n_bits, h_bits, Flavor.SR,
+                                     signed=True)) == order
+
+
+@pytest.mark.parametrize("P", [2, 4, 8])
+def test_plane_permutation_round_trips(P):
+    """q into planes and the output back out are exact inverse
+    permutations, and plane p, lane h*W + w holds element P*w + p of head
+    h: the element that word w of head h stores in field p."""
+    B, K, R, hd = 2, 3, 5, 8 * P
+    x = jnp.asarray(np.random.default_rng(P).normal(size=(B, K, R, hd))
+                    .astype(np.float32))
+    planes = FA._q_planes(x, P)
+    assert planes.shape == (B, P, R, K * hd // P)
+    W = hd // P
+    for p, h, w in [(0, 0, 0), (P - 1, K - 1, W - 1), (1, 2, 3 % W)]:
+        np.testing.assert_array_equal(planes[:, p, :, h * W + w],
+                                      x[:, h, :, P * w + p])
+    np.testing.assert_array_equal(np.asarray(FA._o_unplanes(planes, K)),
+                                  np.asarray(x))
+
+
+@pytest.mark.parametrize("fmt", [F2PFormat(4, 1, Flavor.SR, signed=True),
+                                 FORMATS[1], FORMATS[2]],
+                         ids=lambda f: f"n{f.n_bits}")
+def test_plane_decode_equals_decode_rows_bitwise(fmt):
+    """The kernel body's plane decode (shift + mask per field, scales spread
+    to their heads' lanes by an exact 0/1 matmul) gives every K/V value
+    bitwise what the per-row decode gives, in storage order."""
+    K, hd, T = 3, 32, 16
+    kq = _slab(8, P=1, T=T, K=K, hd=hd, fmt=fmt)
+    words, scales = kq.codes[0], kq.scales[0]       # [T, K*W], [T, K]
+    W = words.shape[-1] // K
+    P = 32 // fmt.n_bits
+    _, _, expand, _ = FA._selectors(K, 1, W, W)
+    got = FA._decode_planes(words, scales, fmt, jnp.asarray(expand))
+    rows = FA._decode_rows(words.reshape(T, K, W), scales[..., None], fmt,
+                           hd)                      # [T, K, hd]
+    want = FA._to_planes(rows[None], P)[0]          # [P, T, K*W]
+    assert len(got) == P
+    for p in range(P):
+        np.testing.assert_array_equal(np.asarray(got[p]),
+                                      np.asarray(want[p]))

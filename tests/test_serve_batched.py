@@ -12,6 +12,8 @@ request's draws; the sequential engine pads partial batches and syncs EOS
 only periodically; and the pool reports word-granular packed bytes through
 the canonical ``packed_nbytes`` accounting.
 """
+import dataclasses
+
 import jax
 import numpy as np
 import pytest
@@ -434,3 +436,21 @@ def test_paged_pool_bytes_page_granular(setup):
     reqs = _requests(cfg, 2, seed=5, max_new=4)
     eng.run(reqs)
     assert eng.pool.stats()["used"] == 1           # all request pages freed
+
+
+@pytest.mark.parametrize("fmt,order", [("f2p_sr_2_8s", "planes"),
+                                       ("f2p_sr_2_6s", "per_head")])
+def test_engine_records_attention_decode_order(setup, fmt, order):
+    """stats["attn_decode_order"] says which decode served: the benchmark
+    cell's MHA rows over its f2p_sr_2_8s pool (the default KV format) take
+    the storage-order planes; 6-bit fields straddle words and keep the
+    per-head unpack."""
+    cfg, params = setup
+    cfg = dataclasses.replace(cfg, n_kv_heads=cfg.n_heads)    # MHA, G=1
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    pol = FormatPolicy(rules=(PolicyRule("kv/*", fmt, 0),))
+    eng = BatchedEngine(cfg, BatchedServeConfig(slots=2, max_seq=32,
+                                                kv_policy=pol), params)
+    out = eng.run(_requests(cfg, 2, max_new=4))
+    assert all(len(t) for t in out.values())
+    assert eng.stats["attn_decode_order"] == order

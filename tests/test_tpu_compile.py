@@ -10,6 +10,9 @@ never at import: only one process may load the TPU library.
 Widths: B=32 decode slots, K=8 kv heads, G=3 query heads per kv head,
 head_dim 128, 8-bit packed F2P KV in 8-token pages, 2048-token span
 (tile 128); quantize/matmul operands are d_model=3072, d_ff=8192.
+``attention_paged_mha`` compiles the paged kernel again at the benchmark
+cell's widths (Phi-3-mini: B=16, K=32 kv heads of 96, G=1), where the
+storage-order planes are 768 lanes of 32 heads.
 """
 import jax
 import jax.numpy as jnp
@@ -30,7 +33,6 @@ FMT6 = F2PFormat(6, 2, Flavor.SR, signed=True)
 B, K, G, HD, T, SPAN = 32, 8, 3, 128, 8, 2048
 D, FF = 3072, 8192
 W8 = packed_words(HD, 8)
-P = (B + 1) * SPAN // T + 1          # the engine's default pool size
 
 
 @pytest.fixture(scope="module")
@@ -62,11 +64,21 @@ def no_compile_cache():
     cc.reset_cache()
 
 
-def _paged(q, kc, ks, vc, vs, pages, kv_len):
-    kq = QTensor.from_parts(kc, ks, FMT8, HD, (P, T, K * HD), packed=True)
-    vq = QTensor.from_parts(vc, vs, FMT8, HD, (P, T, K * HD), packed=True)
-    return FA.attention_paged(q, kq, vq, pages, kv_len=kv_len,
-                              backend="pallas")
+def _paged(b, k, g, hd):
+    """attention_paged over the engine's default pool size for ``b`` slots of
+    ``k`` kv heads of ``hd`` (``g`` query heads each): (fn, arg shapes)."""
+    w, p = packed_words(hd, 8), (b + 1) * SPAN // T + 1
+
+    def fn(q, kc, ks, vc, vs, pages, kv_len):
+        kq = QTensor.from_parts(kc, ks, FMT8, hd, (p, T, k * hd), packed=True)
+        vq = QTensor.from_parts(vc, vs, FMT8, hd, (p, T, k * hd), packed=True)
+        return FA.attention_paged(q, kq, vq, pages, kv_len=kv_len,
+                                  backend="pallas")
+
+    return fn, [((b, 1, k * g, hd), jnp.bfloat16),
+                ((p, T, k * w), jnp.uint32), ((p, T, k), jnp.float32),
+                ((p, T, k * w), jnp.uint32), ((p, T, k), jnp.float32),
+                ((b, SPAN // T), jnp.int32), ((b,), jnp.int32)]
 
 
 def _packed(q, kc, ks, vc, vs, kv_len):
@@ -88,10 +100,8 @@ def _advance(st, budget, u, p, run, logq):
 i32, u32, f32, bf16 = jnp.int32, jnp.uint32, jnp.float32, jnp.bfloat16
 CASES = {
     # the serving decode round's kernels
-    "attention_paged": (_paged, [
-        ((B, 1, K * G, HD), bf16), ((P, T, K * W8), u32), ((P, T, K), f32),
-        ((P, T, K * W8), u32), ((P, T, K), f32), ((B, SPAN // T), i32),
-        ((B,), i32)]),
+    "attention_paged": _paged(B, K, G, HD),
+    "attention_paged_mha": _paged(16, 32, 1, 96),
     "quantize_packed_kv_write": (_kv_write, [((B, 1, K, HD), f32)]),
     # the copy-in path and the packed weight / tensor codecs
     "attention_packed": (_packed, [
@@ -138,10 +148,11 @@ def test_kernel_compiles_for_v5e(one_chip, name):
 
 
 @pytest.mark.parametrize("name", ["quantize_packed_kv_write",
-                                  "attention_paged"])
+                                  "attention_paged", "attention_paged_mha"])
 def test_kernel_compiles_under_highest_matmul_precision(one_chip, name):
     """An ambient ``default_matmul_precision("highest")`` must not reach the
     kernels' bf16 lane-move matmuls (Mosaic refuses f32 contraction of
-    bf16 operands)."""
+    bf16 operands); the attention kernels' f32 selection matmuls pin
+    HIGHEST themselves."""
     with jax.default_matmul_precision("highest"):
         assert "tpu_custom_call" in _compile(*CASES[name], one_chip), name
