@@ -56,7 +56,8 @@ growth, slab binding, mirror uploads, span slice; ``round.launch``;
 ``round.wait``: the host blocked on the token chunk) and ``harvest``
 (harvest, retirement, defrag, starvation check, preemption). Counter
 events: ``slots`` per round (``active``, ``pool_used``, and the kept
-tokens' ``kv_live`` / ``kv_written`` / ``steps_kept``). :data:`PROGRAMS`
+tokens' ``kv_live`` / ``kv_written`` / ``steps_kept``, and ``kv_attended``,
+the KV positions the attention covered for them). :data:`PROGRAMS`
 names the engine's XLA programs.
 
 Bitwise contract (families with ``exact_cobatch``): per-request greedy
@@ -272,6 +273,7 @@ class BatchedEngine:
             bk.append(b)
             b *= 2
         self._span_buckets = tuple(bk) + (maxp,)
+        self._span_tokens = S   # KV positions a row attends: the last span
         if self.paged:
             self._bind_slabs()
         self.slots: list[_Slot | None] = [None] * B
@@ -811,6 +813,7 @@ class BatchedEngine:
                 # no such lever — its dense cache row is [slots, max_seq].
                 span = next((b for b in self._span_buckets if b >= need),
                             self._span_buckets[-1])
+                self._span_tokens = span * self.page_tokens
                 if span < pages.shape[1]:
                     pages = pages[:, :span]
         with obs.span("round.launch"):
@@ -834,7 +837,10 @@ class BatchedEngine:
         (the same stopping rule as :meth:`_harvest`): ``kv_live``, the
         context each kept token's step attended, summed; ``kv_written``,
         the kept tokens (one KV row written each); ``steps_kept``, the
-        round's steps that kept a token for some live request."""
+        round's steps that kept a token for some live request;
+        ``kv_attended``, the KV positions those steps' attention covered:
+        every slot row, live or not, over the round's span, per kept
+        step."""
         live = written = steps = 0
         eos = self.bscfg.eos
         for s, st in enumerate(self.slots):
@@ -852,7 +858,8 @@ class BatchedEngine:
             written += n
             steps = max(steps, n)
         return {"kv_live": live, "kv_written": written,
-                "steps_kept": steps}
+                "steps_kept": steps,
+                "kv_attended": self.bscfg.slots * self._span_tokens * steps}
 
     def _harvest(self, chunk: np.ndarray, results: dict):
         for s, st in enumerate(self.slots):

@@ -137,6 +137,7 @@ class PagedKVPool:
         self.page_tokens = int(page_tokens)
         self.n_pages = int(n_pages)
         self._free = list(range(n_pages))[::-1]   # stack: pop() = lowest last
+        self._free_set = set(self._free)   # membership: free() scans nothing
         self.peak_used = 0
         G, K, hd = cfg.n_groups, cfg.n_kv_heads, cfg.head_dim
         self.attn_keys = [f"b{i}" for i, s in enumerate(cfg.pattern)
@@ -177,14 +178,18 @@ class PagedKVPool:
             raise PoolExhausted(
                 f"need {n} pages, {len(self._free)}/{self.n_pages} free")
         pages = [self._free.pop() for _ in range(n)]
+        self._free_set.difference_update(pages)
         self.peak_used = max(self.peak_used, self.used)
         return pages
 
     def free(self, pages: list[int]) -> None:
+        freed: set[int] = set()
         for p in pages:
-            if not 0 <= p < self.n_pages or p in self._free:
+            if not 0 <= p < self.n_pages or p in self._free_set or p in freed:
                 raise ValueError(f"bad free of page {p}")
+            freed.add(p)
         self._free.extend(sorted(pages, reverse=True))
+        self._free_set |= freed
 
     def extend(self, table: PageTable, n: int) -> list[int]:
         """Grow a live table by ``n`` fresh pages (paged decode's lazy
@@ -307,6 +312,7 @@ class PagedKVPool:
             d = jnp.asarray(dst, jnp.int32)
             self._rebind_slabs(_move_pages_all(self._slab_parts(), s, d))
         self._free = list(range(nxt, self.n_pages))[::-1]
+        self._free_set = set(self._free)
 
     # -- host eviction -----------------------------------------------------
     def evict_to_host(self, table: PageTable) -> HostKV:

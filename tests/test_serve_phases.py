@@ -4,7 +4,8 @@ its XLA programs (serve/batched.py).
 Phases: tracing on changes no output token; the leaf phases nest inside
 ``round`` / ``prefill`` / ``prefill_group`` and tile ``run()``. Counters:
 ``slots``' ``kv_live`` / ``kv_written`` / ``steps_kept`` equal counts
-derived by hand from the requests and their outputs. Programs: each program
+derived by hand from the requests and their outputs, and ``kv_attended``
+the rows and span each round's attention was handed. Programs: each program
 the engine and its pool dispatch lowers to an HLO module that ``PROGRAMS``
 lists.
 """
@@ -200,6 +201,35 @@ def test_counters_equal_hand_counts(setup, slots, group, eos):
             e["steps_kept"] * e["active"])
     if slots == 1:
         assert all(e["steps_kept"] == e["kv_written"] for e in slots_ev)
+
+
+@pytest.mark.parametrize("slots,paged", [(3, True), (5, True), (3, False)],
+                         ids=["paged", "idle-rows", "copy-in"])
+def test_kv_attended_is_rows_times_span_per_kept_step(setup, slots, paged):
+    """``kv_attended`` counts every slot row over the span each round's
+    attention was handed (the page table's width, or max_seq for the dense
+    copy-in row) for each kept step, so it bounds ``kv_live``."""
+    cfg, params = setup
+    reqs = _requests(cfg, 6, new=(20, 60))
+    bs = BatchedServeConfig(slots=slots, max_seq=128, paged_decode=paged)
+    eng = BatchedEngine(cfg, bs, params)
+    spans = []
+    launch = eng._round
+
+    def round_spy(params, caches, tok, pos, req, pages):
+        spans.append(bs.max_seq if pages is None
+                     else pages.shape[1] * eng.page_tokens)
+        return launch(params, caches, tok, pos, req, pages)
+
+    eng._round = round_spy
+    _, tracer, _, _ = _traced(eng, reqs)
+    slots_ev = _counters(tracer, "slots")
+    assert len(slots_ev) == len(spans)
+    if paged:
+        assert len(set(spans)) > 1          # the span bucket moved
+    for e, span in zip(slots_ev, spans):
+        assert e["kv_attended"] == slots * span * e["steps_kept"]
+        assert e["kv_attended"] >= e["kv_live"]
 
 
 def _hlo_module(lowered) -> str:
